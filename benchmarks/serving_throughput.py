@@ -95,6 +95,7 @@ import numpy as np
 from repro.core.routing import (CascadePolicy, HybridRouter,
                                 ThresholdPolicy)
 from repro.data import tokenizer as tok
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import (RouterConfig, build_model, init_router_encoder)
 from repro.models.config import ArchConfig
 from repro.serving import (ContinuousEngine, ContinuousHybridEngine,
@@ -1155,6 +1156,7 @@ def main():
                     help="output JSON path (default: repo-root "
                          "BENCH_serving.json; --smoke defaults to no file)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n = args.requests or (12 if args.smoke else 64)
     t_max = 8 if args.smoke else 32

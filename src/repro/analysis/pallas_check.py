@@ -159,12 +159,12 @@ def _probe_paged_decode() -> None:
     from repro.kernels.paged_decode_attention import kernel as K
     B, Kh, G, D, ps, P, MP = 2, 1, 2, 8, 4, 8, 4
     q = np.zeros((B, Kh, G, D), np.float32)
-    kp = np.zeros((P, ps, Kh, D), np.float32)
+    kp = np.zeros((2, P, Kh, ps, D), np.float32)
     pt = (np.arange(B * MP, dtype=np.int32).reshape(B, MP) % (P - 1)) + 1
     sl = np.array([ps * MP, ps * 2], np.int32)
-    K.paged_decode_attention_gqa(q, kp, kp, pt, sl)
-    K.paged_decode_attention_gqa(q, kp, kp, pt, sl, pages_bound=2)
-    K.paged_decode_attention_gqa(q, kp, kp, pt, sl, pages_bound=4,
+    K.paged_decode_attention_gqa(q, kp, kp, 1, pt, sl)
+    K.paged_decode_attention_gqa(q, kp, kp, 1, pt, sl, pages_bound=2)
+    K.paged_decode_attention_gqa(q, kp, kp, 1, pt, sl, pages_bound=4,
                                  pages_start=1, window=ps)
 
 
@@ -172,14 +172,14 @@ def _probe_paged_prefill() -> None:
     from repro.kernels.paged_prefill_attention import kernel as K
     B, Kh, C, G, D, ps, P, MP = 2, 1, 2, 2, 8, 4, 8, 4
     q = np.zeros((B, Kh, C, G, D), np.float32)
-    kp = np.zeros((P, ps, Kh, D), np.float32)
+    kp = np.zeros((2, P, Kh, ps, D), np.float32)
     pt = (np.arange(B * MP, dtype=np.int32).reshape(B, MP) % (P - 1)) + 1
     start = np.array([ps * 2, ps], np.int32)
     total = start + C
-    K.paged_prefill_attention_gqa(q, kp, kp, pt, start, total)
-    K.paged_prefill_attention_gqa(q, kp, kp, pt, start, total,
+    K.paged_prefill_attention_gqa(q, kp, kp, 1, pt, start, total)
+    K.paged_prefill_attention_gqa(q, kp, kp, 1, pt, start, total,
                                   pages_bound=3)
-    K.paged_prefill_attention_gqa(q, kp, kp, pt, start, total,
+    K.paged_prefill_attention_gqa(q, kp, kp, 1, pt, start, total,
                                   pages_bound=4, pages_start=1, window=ps)
 
 

@@ -9,7 +9,10 @@ kernel on a memory-bandwidth-limited chip.
 
 Grid = (B * K, k_blocks); (m, l, acc) accumulate in VMEM scratch across the
 sequential trailing grid dim. A per-position validity mask (pos <= current,
-window) arrives as an int8 vector blocked alongside K.
+window) arrives as an int32 (BK, 1, S) row blocked alongside K: a
+(1, 1, bk) block keeps the trailing two block dims equal to the array's
+or 128-aligned, which the TPU's compiler requires ((1, bk) over (BK, S)
+is refused), and the v5e's vector unit compares 32-bit, not int8.
 """
 from __future__ import annotations
 
@@ -37,10 +40,10 @@ def _dec_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref,
     q = q_ref[0]          # (G, D)
     k = k_ref[0]          # (bk, D)
     v = v_ref[0]          # (bk, D)
-    valid = valid_ref[0]  # (bk,) int8
+    valid = valid_ref[0]  # (1, bk) int32
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, bk)
-    s = jnp.where(valid[None, :] > 0, s, NEG_INF)
+    s = jnp.where(valid > 0, s, NEG_INF)
 
     m_prev = m_ref[...]
     l_prev = l_ref[...]
@@ -62,7 +65,7 @@ def _dec_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref,
 
 def decode_attention_gqa(q, k, v, valid, *, bk: int = 512,
                          interpret: bool | None = None):
-    """q: (BK, G, D) pre-scaled; k, v: (BK, S, D); valid: (BK, S) int8.
+    """q: (BK, G, D) pre-scaled; k, v: (BK, S, D); valid: (BK, S) int.
 
     Returns (BK, G, D). BK = batch * n_kv_heads; G = n_heads / n_kv_heads.
     Irregular S is padded up to a block multiple (padding arrives masked via
@@ -87,7 +90,7 @@ def decode_attention_gqa(q, k, v, valid, *, bk: int = 512,
             pl.BlockSpec((1, G, D), lambda b, ki: (b, 0, 0)),
             pl.BlockSpec((1, bk, D), lambda b, ki: (b, ki, 0)),
             pl.BlockSpec((1, bk, D), lambda b, ki: (b, ki, 0)),
-            pl.BlockSpec((1, bk), lambda b, ki: (b, ki)),
+            pl.BlockSpec((1, 1, bk), lambda b, ki: (b, 0, ki)),
         ],
         out_specs=pl.BlockSpec((1, G, D), lambda b, ki: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BK, G, D), q.dtype),
@@ -97,4 +100,4 @@ def decode_attention_gqa(q, k, v, valid, *, bk: int = 512,
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, valid)
+    )(q, k, v, valid.astype(jnp.int32)[:, None, :])

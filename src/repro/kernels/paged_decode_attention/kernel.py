@@ -36,8 +36,14 @@ fully masked.
 
 Layouts:
   q        (B, K, G, D)   pre-scaled; G = n_heads / n_kv_heads
-  k_pages  (P, ps, K, D)  shared page pool (P pages of ps tokens)
-  v_pages  (P, ps, K, D)
+  k_pages  (L, P, K, ps, D)  shared page pool of every layer (P pages of
+  v_pages  (L, P, K, ps, D)  ps tokens); one page's slice for one KV head
+                             is a whole trailing (ps, D) tile, the block
+                             the TPU's compiler accepts (a (ps, 1, D) slice
+                             is not). ``layer`` (a traced scalar, prefetched
+                             with the page table) picks the layer read, so
+                             a layer scan hands the kernel the whole pool
+                             and no layer's pages are ever copied out.
   page_table (B, MP) int32; seq_lens (B,) int32
 Grid = (B, K, pages_bound or MP); (m, l, acc) accumulate in VMEM scratch
 across the sequential trailing page dim, exactly like the dense decode
@@ -55,7 +61,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(pt_ref, sl_ref, li_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, page_size: int, window: int,
                   pages_start: int):
     b = pl.program_id(0)
@@ -69,8 +75,8 @@ def _paged_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0]        # (G, D)
-    k = k_ref[0, :, 0, :]  # (ps, D)
-    v = v_ref[0, :, 0, :]  # (ps, D)
+    k = k_ref[0, 0, 0]     # (ps, D)
+    v = v_ref[0, 0, 0]     # (ps, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, ps)
     kpos = (pages_start + p) * page_size + jax.lax.broadcasted_iota(
@@ -103,12 +109,13 @@ def _paged_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
                        ).astype(o_ref.dtype)
 
 
-def paged_decode_attention_gqa(q, k_pages, v_pages, page_table, seq_lens, *,
-                               pages_bound: int | None = None,
+def paged_decode_attention_gqa(q, k_pages, v_pages, layer, page_table,
+                               seq_lens, *, pages_bound: int | None = None,
                                pages_start: int = 0, window: int = 0,
                                interpret: bool | None = None):
-    """q: (B, K, G, D) pre-scaled; k_pages/v_pages: (P, ps, K, D);
-    page_table: (B, MP) int32; seq_lens: (B,) int32.
+    """q: (B, K, G, D) pre-scaled; k_pages/v_pages: (L, P, K, ps, D);
+    ``layer``: int32 scalar (may be traced) naming the layer of the pools
+    to read; page_table: (B, MP) int32; seq_lens: (B,) int32.
 
     ``pages_bound``: static bound on the sequential page walk — the caller
     guarantees every seq_len fits in ``pages_bound`` pages (live-bounded
@@ -124,7 +131,7 @@ def paged_decode_attention_gqa(q, k_pages, v_pages, page_table, seq_lens, *,
     from repro.kernels.common import default_interpret
     interpret = default_interpret(interpret)
     B, K, G, D = q.shape
-    _, ps, Kk, Dk = k_pages.shape
+    _, _, Kk, ps, Dk = k_pages.shape
     assert (Kk, Dk) == (K, D), (k_pages.shape, q.shape)
     MP = page_table.shape[1]
     end = MP if pages_bound is None else pages_bound
@@ -134,19 +141,20 @@ def paged_decode_attention_gqa(q, k_pages, v_pages, page_table, seq_lens, *,
     NP = end - pages_start
     assert 1 <= NP and end <= MP, (pages_bound, pages_start, MP)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, K, NP),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, p, pt, sl: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, p, pt, sl: (pt[b, pages_start + p],
-                                                  0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, p, pt, sl: (pt[b, pages_start + p],
-                                                  0, h, 0)),
+            pl.BlockSpec((1, 1, G, D),
+                         lambda b, h, p, pt, sl, li: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, ps, D),
+                         lambda b, h, p, pt, sl, li:
+                         (li[0], pt[b, pages_start + p], h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, ps, D),
+                         lambda b, h, p, pt, sl, li:
+                         (li[0], pt[b, pages_start + p], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, p, pt, sl: (b, h, 0, 0)),
+                               lambda b, h, p, pt, sl, li: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -160,4 +168,4 @@ def paged_decode_attention_gqa(q, k_pages, v_pages, page_table, seq_lens, *,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+      jnp.asarray(layer, jnp.int32).reshape(1), q, k_pages, v_pages)

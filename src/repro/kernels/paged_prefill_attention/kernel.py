@@ -34,8 +34,10 @@ so they contribute exactly zero.
 
 Layouts:
   q        (B, K, C, G, D)  pre-scaled chunk queries; G = n_heads / n_kv_heads
-  k_pages  (P, ps, K, D)    shared page pool (P pages of ps tokens)
-  v_pages  (P, ps, K, D)
+  k_pages  (L, P, K, ps, D) shared page pool of every layer (P pages of ps
+  v_pages  (L, P, K, ps, D) tokens); one page's slice for one KV head is a
+                            whole trailing (ps, D) tile, and ``layer`` (a
+                            prefetched scalar) picks the layer read
   page_table (B, MP) int32; start (B,) int32; total (B,) int32
 Grid = (B, K, pages_bound or MP); q is flattened to (B, K, C*G, D) rows
 (c-major) so each grid step is one (C*G, ps) score tile. ``pages_bound``
@@ -56,8 +58,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_prefill_kernel(pt_ref, st_ref, tl_ref, q_ref, k_ref, v_ref, o_ref,
-                          m_ref, l_ref, acc_ref, *, page_size: int,
+def _paged_prefill_kernel(pt_ref, st_ref, tl_ref, li_ref, q_ref, k_ref, v_ref,
+                          o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
                           group: int, window: int, pages_start: int):
     b = pl.program_id(0)
     p = pl.program_id(2)
@@ -70,8 +72,8 @@ def _paged_prefill_kernel(pt_ref, st_ref, tl_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0]        # (CG, D) chunk-row-major: row = c * group + g
-    k = k_ref[0, :, 0, :]  # (ps, D)
-    v = v_ref[0, :, 0, :]  # (ps, D)
+    k = k_ref[0, 0, 0]     # (ps, D)
+    v = v_ref[0, 0, 0]     # (ps, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (CG, ps)
 
@@ -106,13 +108,15 @@ def _paged_prefill_kernel(pt_ref, st_ref, tl_ref, q_ref, k_ref, v_ref, o_ref,
                        ).astype(o_ref.dtype)
 
 
-def paged_prefill_attention_gqa(q, k_pages, v_pages, page_table, start,
-                                total, *, pages_bound: int | None = None,
+def paged_prefill_attention_gqa(q, k_pages, v_pages, layer, page_table,
+                                start, total, *,
+                                pages_bound: int | None = None,
                                 pages_start: int = 0, window: int = 0,
                                 interpret: bool | None = None):
-    """q: (B, K, C, G, D) pre-scaled; k_pages/v_pages: (P, ps, K, D);
-    page_table: (B, MP) int32; start/total: (B,) int32 (tokens resident
-    before the chunk / after it: ``total = start + n_new``).
+    """q: (B, K, C, G, D) pre-scaled; k_pages/v_pages: (L, P, K, ps, D);
+    ``layer``: int32 scalar (may be traced) naming the layer of the pools
+    to read; page_table: (B, MP) int32; start/total: (B,) int32 (tokens
+    resident before the chunk / after it: ``total = start + n_new``).
 
     ``pages_bound``: static bound on the sequential page walk — the caller
     guarantees every ``total`` fits in ``pages_bound`` pages (live-bounded
@@ -128,7 +132,7 @@ def paged_prefill_attention_gqa(q, k_pages, v_pages, page_table, start,
     from repro.kernels.common import default_interpret
     interpret = default_interpret(interpret)
     B, K, C, G, D = q.shape
-    _, ps, Kk, Dk = k_pages.shape
+    _, _, Kk, ps, Dk = k_pages.shape
     assert (Kk, Dk) == (K, D), (k_pages.shape, q.shape)
     MP = page_table.shape[1]
     end = MP if pages_bound is None else pages_bound
@@ -139,20 +143,20 @@ def paged_prefill_attention_gqa(q, k_pages, v_pages, page_table, start,
     assert 1 <= NP and end <= MP, (pages_bound, pages_start, MP)
     CG = C * G
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, K, NP),
         in_specs=[
             pl.BlockSpec((1, 1, CG, D),
-                         lambda b, h, p, pt, st, tl: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, p, pt, st, tl:
-                         (pt[b, pages_start + p], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, p, pt, st, tl:
-                         (pt[b, pages_start + p], 0, h, 0)),
+                         lambda b, h, p, pt, st, tl, li: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, ps, D),
+                         lambda b, h, p, pt, st, tl, li:
+                         (li[0], pt[b, pages_start + p], h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, ps, D),
+                         lambda b, h, p, pt, st, tl, li:
+                         (li[0], pt[b, pages_start + p], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, CG, D),
-                               lambda b, h, p, pt, st, tl: (b, h, 0, 0)),
+                               lambda b, h, p, pt, st, tl, li: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((CG, 1), jnp.float32),
             pltpu.VMEM((CG, 1), jnp.float32),
@@ -166,5 +170,6 @@ def paged_prefill_attention_gqa(q, k_pages, v_pages, page_table, start,
         out_shape=jax.ShapeDtypeStruct((B, K, CG, D), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
-      total.astype(jnp.int32), q.reshape(B, K, CG, D), k_pages, v_pages)
+      total.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(B, K, CG, D), k_pages, v_pages)
     return out.reshape(B, K, C, G, D)

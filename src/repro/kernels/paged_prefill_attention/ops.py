@@ -1,8 +1,9 @@
 """Jit'd public wrapper for paged GQA prefill attention (chunked prefill).
 
 Model layout in: q (B, C, H, D) pre-scaled (one chunk of C query tokens per
-request), the shared page pool (P, ps, K, D), the request's page-table row(s)
-(B, MP), and the per-request start/total lengths. Regroups q to the kernel's
+request), the shared page pools of every layer (L, P, K, ps, D) and the layer
+to read, the request's page-table row(s) (B, MP), and the per-request
+start/total lengths. Regroups q to the kernel's
 (B, K, C, G, D) GQA layout (heads grouped per KV head).
 
 This chunked-prefill shape doubles as the speculative *verify* shape: a
@@ -32,10 +33,10 @@ STATIC_ARGS = ("pages_bound", "pages_start", "window")
 
 @functools.partial(jax.jit, static_argnames=("pages_bound", "pages_start",
                                              "window"))
-def paged_prefill_attention(q, k_pages, v_pages, page_table, start, total,
-                            pages_bound=None, pages_start=0, window=0):
-    """q: (B, C, H, D) pre-scaled; k_pages/v_pages: (P, ps, K, D);
-    page_table: (B, MP); start/total: (B,). ``pages_bound``: static live
+def paged_prefill_attention(q, k_pages, v_pages, layer, page_table, start,
+                            total, pages_bound=None, pages_start=0, window=0):
+    """q: (B, C, H, D) pre-scaled; k_pages/v_pages: (L, P, K, ps, D);
+    layer: int32 scalar; page_table: (B, MP); start/total: (B,). ``pages_bound``: static live
     bound on the page walk (None = full static width); ``window``/
     ``pages_start``: static sliding-window size (0 = global) and first
     walked page (window layers only). Returns (B, C, H, D)."""
@@ -43,7 +44,7 @@ def paged_prefill_attention(q, k_pages, v_pages, page_table, start, total,
     K = k_pages.shape[2]
     G = H // K
     qg = jnp.transpose(q.reshape(B, C, K, G, D), (0, 2, 1, 3, 4))
-    out = paged_prefill_attention_gqa(qg, k_pages, v_pages, page_table,
-                                      start, total, pages_bound=pages_bound,
+    out = paged_prefill_attention_gqa(qg, k_pages, v_pages, layer,
+                                      page_table, start, total, pages_bound=pages_bound,
                                       pages_start=pages_start, window=window)
     return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, C, H, D)

@@ -18,7 +18,7 @@ NEG_INF = -1e30
 def paged_prefill_attention_ref(q, k_pages, v_pages, page_table, start,
                                 total, pages_bound=None, pages_start=0,
                                 window=0):
-    """q: (B, K, C, G, D) pre-scaled; k_pages/v_pages: (P, ps, K, D);
+    """q: (B, K, C, G, D) pre-scaled; k_pages/v_pages: (P, K, ps, D);
     page_table: (B, MP) int32; start/total: (B,) int32. ``pages_bound``:
     static live bound on the page walk (every ``total`` must fit in that
     many pages); None gathers the full table width. ``window``: static
@@ -27,15 +27,15 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, page_table, start,
     earliest in-window key must be ``>= pages_start * ps``).
     Returns (B, K, C, G, D)."""
     B, K, C, G, D = q.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     assert pages_start == 0 or window > 0, (pages_start, window)
     end = page_table.shape[1] if pages_bound is None else pages_bound
     page_table = page_table[:, pages_start:end]
     MP = page_table.shape[1]
     S = MP * ps
-    # (B, MP, ps, K, D) -> (B, K, MP*ps, D)
-    k = jnp.moveaxis(k_pages[page_table], 3, 1).reshape(B, K, S, D)
-    v = jnp.moveaxis(v_pages[page_table], 3, 1).reshape(B, K, S, D)
+    # (B, MP, K, ps, D) -> (B, K, MP*ps, D)
+    k = jnp.moveaxis(k_pages[page_table], 2, 1).reshape(B, K, S, D)
+    v = jnp.moveaxis(v_pages[page_table], 2, 1).reshape(B, K, S, D)
     s = jnp.einsum("bkcgd,bksd->bkcgs", q, k).astype(jnp.float32)
     kpos = pages_start * ps + jnp.arange(S)
     qpos = start[:, None] + jnp.arange(C)                     # (B, C)

@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -24,7 +24,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             "dry-run launcher must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(model: int = 1) -> Mesh:
@@ -32,4 +33,5 @@ def make_host_mesh(model: int = 1) -> Mesh:
     n = len(jax.devices())
     data = n // model
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:data * model])
+                         devices=jax.devices()[:data * model],
+                         axis_types=(AxisType.Auto,) * 2)

@@ -65,6 +65,7 @@ from repro.core.quality import edit_similarity
 from repro.core.router import RouterTrainConfig, score_dataset, train_router
 from repro.data import tokenizer as tok
 from repro.data.tasks import generate_dataset, lm_training_arrays
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import RouterConfig, build_model
 from repro.serving import (ContinuousEngine, ContinuousPoolEngine,
                            HybridEngine, make_engine)
@@ -195,6 +196,7 @@ def main():
                          "resumes one tier up as ONE chunked prefill of "
                          "(prompt + emitted prefix), greedy-exact")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.spec_gamma and not args.continuous:
         raise SystemExit("--spec-gamma rides the continuous pool's step "
                          "plane; pass --continuous")

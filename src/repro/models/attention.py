@@ -154,14 +154,16 @@ def init_kv_cache(cfg, batch: int, max_seq: int, n_layers: int, dtype=None):
 
 def init_paged_kv_cache(cfg, num_pages: int, page_size: int, n_layers: int,
                         dtype=None):
-    """Shared page pool: (n_layers, num_pages, page_size, K, Dh) per tensor.
+    """Shared page pool: (n_layers, num_pages, K, page_size, Dh) per tensor
+    — a page's tokens for one KV head form a trailing (page_size, Dh) tile,
+    the block the paged kernels read.
 
     Page 0 is reserved as the pool's scratch page (writes for inactive slots
     and masked reads land there); allocators hand out pages >= 1.
     """
     K, Dh = cfg.n_kv_heads, cfg.resolved_head_dim
     dt = dtype or dtype_of(cfg)
-    shape = (n_layers, num_pages, page_size, K, Dh)
+    shape = (n_layers, num_pages, K, page_size, Dh)
     return {"k_pages": jnp.zeros(shape, dt), "v_pages": jnp.zeros(shape, dt)}
 
 
@@ -240,13 +242,16 @@ def decode_attention(params, x_t, layer_k, layer_v, pos, cfg, *,
     return _out_proj(params, out, B, 1, H, Dh), layer_k, layer_v
 
 
-def paged_decode_attention(params, x_t, k_pages, v_pages, page_table,
+def paged_decode_attention(params, x_t, k_pages, v_pages, layer, page_table,
                            seq_lens, active, cfg, pages_bound=None, *,
                            window=0, pages_start=0):
     """One decode step against a paged KV cache (continuous batching).
 
     x_t: (B, 1, D) — one new token per serving slot. k_pages/v_pages:
-    (P, ps, K, Dh) shared pool; page_table: (B, MP); seq_lens: (B,) tokens
+    (L, P, K, ps, Dh) shared pools of every attention layer, of which this
+    is ``layer`` (int32 scalar, traced under a layer scan) — the new K/V is
+    written into that layer in place and the kernel reads it there, so no
+    layer's pool is sliced out; page_table: (B, MP); seq_lens: (B,) tokens
     already in each slot's cache (the new token lands at index seq_lens);
     active: (B,) bool — inactive slots write to the reserved scratch page 0
     and their output is garbage the engine masks. ``pages_bound``: static
@@ -261,29 +266,35 @@ def paged_decode_attention(params, x_t, k_pages, v_pages, page_table,
     """
     B = x_t.shape[0]
     H, K, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[3]
     MP = page_table.shape[1]
     cap = MP * ps
     pos = jnp.minimum(seq_lens, cap - 1)                  # write position
     q, k_t, v_t = _project_qkv(params, x_t, cfg, pos[:, None])
     page = page_table[jnp.arange(B), pos // ps]           # (B,)
     page = jnp.where(active, page, 0)                     # scratch for idle
-    k_pages = k_pages.at[page, pos % ps].set(k_t[:, 0])
-    v_pages = v_pages.at[page, pos % ps].set(v_t[:, 0])
+    # every index explicit, so the scatter's window is the trailing Dh
+    # row alone and the pool keeps the row-major layout the kernel reads
+    heads = jnp.arange(K)[None, :]
+    at = (layer, page[:, None], heads, (pos % ps)[:, None])
+    k_pages = k_pages.at[at].set(k_t[:, 0])
+    v_pages = v_pages.at[at].set(v_t[:, 0])
     lens = jnp.minimum(seq_lens + 1, cap)                 # incl. new token
     scale = Dh ** -0.5
     qg = (q[:, 0] * scale).reshape(B, K, H // K, Dh)
     if cfg.use_pallas:
         from repro.kernels.paged_decode_attention.kernel import \
             paged_decode_attention_gqa
-        out = paged_decode_attention_gqa(qg, k_pages, v_pages, page_table,
-                                         lens, pages_bound=pages_bound,
+        out = paged_decode_attention_gqa(qg, k_pages, v_pages, layer,
+                                         page_table, lens,
+                                         pages_bound=pages_bound,
                                          pages_start=pages_start,
                                          window=window)
     else:
         from repro.kernels.paged_decode_attention.ref import \
             paged_decode_attention_ref
-        out = paged_decode_attention_ref(qg, k_pages, v_pages, page_table,
+        out = paged_decode_attention_ref(qg, k_pages[layer], v_pages[layer],
+                                         page_table,
                                          lens, pages_bound=pages_bound,
                                          pages_start=pages_start,
                                          window=window)
@@ -291,14 +302,15 @@ def paged_decode_attention(params, x_t, k_pages, v_pages, page_table,
     return _out_proj(params, out, B, 1, H, Dh), k_pages, v_pages
 
 
-def paged_prefill_attention(params, x, k_pages, v_pages, page_table, start,
-                            n_new, cfg, pages_bound=None, *, window=0,
+def paged_prefill_attention(params, x, k_pages, v_pages, layer, page_table,
+                            start, n_new, cfg, pages_bound=None, *, window=0,
                             pages_start=0):
     """One chunked-prefill step against a paged KV cache.
 
     x: (B, C, D) — a fixed-width chunk of prompt activations per serving
     slot, of which the first ``n_new[b]`` rows are real tokens (the rest is
-    bucket padding). k_pages/v_pages: (P, ps, K, Dh) shared pool;
+    bucket padding). k_pages/v_pages: (L, P, K, ps, Dh) shared pools of
+    every attention layer, of which this is ``layer`` (int32 scalar);
     page_table: (B, MP) the slot's page-table row; start: (B,) tokens
     already resident (the chunk occupies global positions
     ``start .. start + n_new - 1``).
@@ -316,7 +328,7 @@ def paged_prefill_attention(params, x, k_pages, v_pages, page_table, start,
     """
     B, C, D = x.shape
     H, K, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[3]
     MP = page_table.shape[1]
     cap = MP * ps
     positions = start[:, None] + jnp.arange(C)[None, :]       # (B, C)
@@ -327,8 +339,10 @@ def paged_prefill_attention(params, x, k_pages, v_pages, page_table, start,
     valid = jnp.arange(C)[None, :] < n_new[:, None]
     page = jnp.take_along_axis(page_table, pos // ps, axis=1)  # (B, C)
     page = jnp.where(valid, page, 0)
-    k_pages = k_pages.at[page, pos % ps].set(k_c)
-    v_pages = v_pages.at[page, pos % ps].set(v_c)
+    at = (layer, page[:, :, None], jnp.arange(K)[None, None, :],
+          (pos % ps)[:, :, None])
+    k_pages = k_pages.at[at].set(k_c)
+    v_pages = v_pages.at[at].set(v_c)
     total = start + n_new
     scale = Dh ** -0.5
     G = H // K
@@ -336,15 +350,16 @@ def paged_prefill_attention(params, x, k_pages, v_pages, page_table, start,
     if cfg.use_pallas:
         from repro.kernels.paged_prefill_attention.kernel import \
             paged_prefill_attention_gqa
-        out = paged_prefill_attention_gqa(qg, k_pages, v_pages, page_table,
-                                          start, total,
+        out = paged_prefill_attention_gqa(qg, k_pages, v_pages, layer,
+                                          page_table, start, total,
                                           pages_bound=pages_bound,
                                           pages_start=pages_start,
                                           window=window)
     else:
         from repro.kernels.paged_prefill_attention.ref import \
             paged_prefill_attention_ref
-        out = paged_prefill_attention_ref(qg, k_pages, v_pages, page_table,
+        out = paged_prefill_attention_ref(qg, k_pages[layer], v_pages[layer],
+                                          page_table,
                                           start, total,
                                           pages_bound=pages_bound,
                                           pages_start=pages_start,

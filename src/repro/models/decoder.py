@@ -18,7 +18,7 @@ from . import attention as attn
 from . import moe as moe_lib
 from . import ssm as ssm_lib
 from .common import (dtype_of, embed, init_embedding, init_mlp, init_rmsnorm,
-                     mlp, rmsnorm, stack_params, unembed)
+                     mlp, rmsnorm, unembed)
 from repro.sharding.context import constrain_batch
 
 
@@ -42,7 +42,9 @@ def init_layer(key, cfg) -> dict:
 def init_decoder(key, cfg) -> dict:
     k_emb, k_layers, k_head = jax.random.split(key, 3)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    layers = stack_params([init_layer(k, cfg) for k in layer_keys])
+    # vmapped over the layer keys: the same values as stacking per-layer
+    # inits, traced (and compiled) once rather than n_layers times
+    layers = jax.vmap(lambda k: init_layer(k, cfg))(layer_keys)
     params = {
         "embed": init_embedding(k_emb, cfg),
         "layers": layers,
@@ -241,12 +243,6 @@ def _attn_layer_runs(cfg):
     return [tuple(r) for r in runs]
 
 
-def _slice_layers(tree, i0: int, n: int):
-    """Slice a stacked-params pytree (leading layer axis) to layers
-    [i0, i0 + n)."""
-    return jax.tree_util.tree_map(lambda a: a[i0:i0 + n], tree)
-
-
 def decoder_prefill_paged_chunk(params, cache, tokens, page_table, start,
                                 n_new, cfg, pages_bound=None,
                                 window_start=0, state_rows=None):
@@ -310,42 +306,59 @@ def decoder_prefill_paged_chunk(params, cache, tokens, page_table, start,
     return x_last, {**cache, "k_pages": kps, "v_pages": vps}
 
 
-def _paged_chunk_attn_hidden(params, cache, x, page_table, start, n_new, cfg,
-                             pages_bound, window_start):
-    """Shared attention-family chunk body: run every same-window layer run
-    over the embedded chunk ``x`` (B, C, D) — each layer writing the
-    chunk's K/V straight into the pool pages and attending causally to
-    resident context + in-chunk keys — then the final norm. Returns
-    (x (B, C, D) post-norm hidden states for EVERY chunk position, kps,
-    vps). ``decoder_prefill_paged_chunk`` keeps only the carry position;
-    ``decoder_verify_paged_chunk`` returns all of them."""
+def _scan_attn_runs(make_attn, params, cache, x, cfg, window_start):
+    """Run every same-window layer run over ``x`` — attention (``make_attn
+    (window, pages_start)`` returns the layer's paged attention call) then
+    the FFN — with the whole (L, P, K, ps, Dh) pools in the scan carry:
+    each layer writes its K/V into its own layer of the pools in place and
+    its kernel reads that layer there, so no run slices the pools out or
+    concatenates them back (at gemma3-4b serving widths that would hold
+    three copies of a 2.3 GB pool beside 7.8 GB of weights). Returns (x,
+    k_pages, v_pages)."""
     def make_body(window):
-        def body(x, xs):
-            layer_p, kp, vp = xs
+        attend = make_attn(window, window_start if window else 0)
+
+        def body(carry, li):
+            x, kp, vp = carry
+            # index the whole stack in the loop: slicing each run's weights
+            # out before its scan would copy them
+            layer_p = jax.tree_util.tree_map(lambda a: a[li],
+                                             params["layers"])
             h = rmsnorm(layer_p["ln1"], x, cfg.norm_eps)
-            o, kp, vp = attn.paged_prefill_attention(
-                layer_p["attn"], h, kp, vp, page_table, start, n_new, cfg,
-                pages_bound, window=window,
-                pages_start=window_start if window else 0)
+            o, kp, vp = attend(layer_p["attn"], h, kp, vp, li)
             x = x + o
             h = rmsnorm(layer_p["ln2"], x, cfg.norm_eps)
             if cfg.n_experts > 0:
                 y, _ = moe_lib.moe_forward(layer_p["moe"], h, cfg)
             else:
                 y = mlp(layer_p["mlp"], h)
-            return constrain_batch(x + y), (kp, vp)
+            return (constrain_batch(x + y), kp, vp), None
         return body
 
-    seg_k, seg_v = [], []
+    kp, vp = cache["k_pages"], cache["v_pages"]
     for w, i0, n in _attn_layer_runs(cfg):
-        x, (kps, vps) = jax.lax.scan(
-            make_body(w), x,
-            (_slice_layers(params["layers"], i0, n),
-             cache["k_pages"][i0:i0 + n], cache["v_pages"][i0:i0 + n]))
-        seg_k.append(kps)
-        seg_v.append(vps)
-    kps = seg_k[0] if len(seg_k) == 1 else jnp.concatenate(seg_k)
-    vps = seg_v[0] if len(seg_v) == 1 else jnp.concatenate(seg_v)
+        (x, kp, vp), _ = jax.lax.scan(
+            make_body(w), (x, kp, vp),
+            jnp.arange(i0, i0 + n, dtype=jnp.int32))
+    return x, kp, vp
+
+
+def _paged_chunk_attn_hidden(params, cache, x, page_table, start, n_new, cfg,
+                             pages_bound, window_start):
+    """Shared attention-family chunk body: run every layer over the
+    embedded chunk ``x`` (B, C, D) — each layer writing the chunk's K/V
+    straight into the pool pages and attending causally to resident
+    context + in-chunk keys — then the final norm. Returns (x (B, C, D)
+    post-norm hidden states for EVERY chunk position, kps, vps).
+    ``decoder_prefill_paged_chunk`` keeps only the carry position;
+    ``decoder_verify_paged_chunk`` returns all of them."""
+    def make_attn(window, pages_start):
+        return lambda p, h, kp, vp, li: attn.paged_prefill_attention(
+            p, h, kp, vp, li, page_table, start, n_new, cfg, pages_bound,
+            window=window, pages_start=pages_start)
+
+    x, kps, vps = _scan_attn_runs(make_attn, params, cache, x, cfg,
+                                  window_start)
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
     return x, kps, vps
 
@@ -415,33 +428,13 @@ def decoder_decode_step_paged(params, cache, token, page_table, seq_lens,
         logits = _unembed(params, x, cfg)[:, 0]
         return logits, {**cache, "rec": rec}
 
-    def make_body(window):
-        def body(x, xs):
-            layer_p, kp, vp = xs
-            h = rmsnorm(layer_p["ln1"], x, cfg.norm_eps)
-            o, kp, vp = attn.paged_decode_attention(
-                layer_p["attn"], h, kp, vp, page_table, seq_lens, active,
-                cfg, pages_bound, window=window,
-                pages_start=window_start if window else 0)
-            x = x + o
-            h = rmsnorm(layer_p["ln2"], x, cfg.norm_eps)
-            if cfg.n_experts > 0:
-                y, _ = moe_lib.moe_forward(layer_p["moe"], h, cfg)
-            else:
-                y = mlp(layer_p["mlp"], h)
-            return constrain_batch(x + y), (kp, vp)
-        return body
+    def make_attn(window, pages_start):
+        return lambda p, h, kp, vp, li: attn.paged_decode_attention(
+            p, h, kp, vp, li, page_table, seq_lens, active, cfg, pages_bound,
+            window=window, pages_start=pages_start)
 
-    seg_k, seg_v = [], []
-    for w, i0, n in _attn_layer_runs(cfg):
-        x, (kps, vps) = jax.lax.scan(
-            make_body(w), x,
-            (_slice_layers(params["layers"], i0, n),
-             cache["k_pages"][i0:i0 + n], cache["v_pages"][i0:i0 + n]))
-        seg_k.append(kps)
-        seg_v.append(vps)
-    kps = seg_k[0] if len(seg_k) == 1 else jnp.concatenate(seg_k)
-    vps = seg_v[0] if len(seg_v) == 1 else jnp.concatenate(seg_v)
+    x, kps, vps = _scan_attn_runs(make_attn, params, cache, x, cfg,
+                                  window_start)
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
     logits = _unembed(params, x, cfg)[:, 0]
     return logits, {**cache, "k_pages": kps, "v_pages": vps}

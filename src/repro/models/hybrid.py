@@ -175,7 +175,7 @@ def hybrid_prefill(params, batch, cfg, max_seq: int | None = None):
 # ------------------------------------------------------- paged (continuous)
 def init_hybrid_paged_cache(cfg, num_pages: int, page_size: int):
     """Paged KV pool for the hybrid stack's attention layers (one per
-    block): (n_blocks, num_pages, page_size, K, Dh) per tensor, page 0
+    block): (n_blocks, num_pages, K, page_size, Dh) per tensor, page 0
     reserved as scratch. The Mamba layers' state lives in the recurrent
     pool (init_hybrid_recurrent_state), not here."""
     if not cfg.supports_paged_kv:
@@ -235,16 +235,17 @@ def hybrid_prefill_paged_chunk(params, cache, tokens, page_table, start,
     h0 = jnp.moveaxis(h0, 0, 1)                   # (nb, B, nm, ...)
     tails = jnp.moveaxis(tails, 0, 1)
 
-    def block_fn(x, xs):
-        block_p, kp, vp, h_sts, tls = xs          # h_sts: (B, nm, ...)
+    def block_fn(carry, xs):
+        x, kp, vp = carry                         # whole-stack pools
+        block_p, bi, h_sts, tls = xs              # h_sts: (B, nm, ...)
         jm = jmoe = jmlp = 0
         new_states, new_tails = [], []
         for (mixer, ffn) in layout:
             if mixer == "attn":
                 h = rmsnorm(block_p["attn"]["ln1"], x, cfg.norm_eps)
                 o, kp, vp = attn.paged_prefill_attention(
-                    block_p["attn"]["attn"], h, kp, vp, page_table, start,
-                    n_new, cfg, pages_bound)
+                    block_p["attn"]["attn"], h, kp, vp, bi, page_table,
+                    start, n_new, cfg, pages_bound)
                 x = x + o
             else:
                 p = _take(block_p["mamba"], jm)
@@ -260,12 +261,13 @@ def hybrid_prefill_paged_chunk(params, cache, tokens, page_table, start,
                 jmoe += 1
             else:
                 jmlp += 1
-        return constrain_batch(x), (kp, vp, jnp.stack(new_states, axis=1),
-                                    jnp.stack(new_tails, axis=1))
+        return (constrain_batch(x), kp, vp), (jnp.stack(new_states, axis=1),
+                                              jnp.stack(new_tails, axis=1))
 
-    x, (kps, vps, states, new_tails) = jax.lax.scan(
-        block_fn, x, (params["blocks"], cache["k_pages"], cache["v_pages"],
-                      h0, tails))
+    n_blocks = cache["k_pages"].shape[0]
+    (x, kps, vps), (states, new_tails) = jax.lax.scan(
+        block_fn, (x, cache["k_pages"], cache["v_pages"]),
+        (params["blocks"], jnp.arange(n_blocks, dtype=jnp.int32), h0, tails))
     rec = {"h": rec["h"].at[state_rows].set(jnp.moveaxis(states, 0, 1)),
            "conv": rec["conv"].at[state_rows].set(
                jnp.moveaxis(new_tails, 0, 1))}
@@ -293,15 +295,16 @@ def hybrid_decode_step_paged(params, cache, token, page_table, seq_lens,
     h_all = jnp.moveaxis(rec["h"][1:], 0, 1)      # (nb, B, nm, ...)
     t_all = jnp.moveaxis(rec["conv"][1:], 0, 1)
 
-    def block_fn(x, xs):
-        block_p, kp, vp, h_sts, tls = xs
+    def block_fn(carry, xs):
+        x, kp, vp = carry                         # whole-stack pools
+        block_p, bi, h_sts, tls = xs
         jm = jmoe = jmlp = 0
         new_states, new_tails = [], []
         for (mixer, ffn) in layout:
             if mixer == "attn":
                 h = rmsnorm(block_p["attn"]["ln1"], x, cfg.norm_eps)
                 o, kp, vp = attn.paged_decode_attention(
-                    block_p["attn"]["attn"], h, kp, vp, page_table,
+                    block_p["attn"]["attn"], h, kp, vp, bi, page_table,
                     seq_lens, active, cfg, pages_bound)
                 x = x + o
             else:
@@ -322,12 +325,14 @@ def hybrid_decode_step_paged(params, cache, token, page_table, seq_lens,
                 jmoe += 1
             else:
                 jmlp += 1
-        return constrain_batch(x), (kp, vp, jnp.stack(new_states, axis=1),
-                                    jnp.stack(new_tails, axis=1))
+        return (constrain_batch(x), kp, vp), (jnp.stack(new_states, axis=1),
+                                              jnp.stack(new_tails, axis=1))
 
-    x, (kps, vps, states, tails) = jax.lax.scan(
-        block_fn, x, (params["blocks"], cache["k_pages"], cache["v_pages"],
-                      h_all, t_all))
+    n_blocks = cache["k_pages"].shape[0]
+    (x, kps, vps), (states, tails) = jax.lax.scan(
+        block_fn, (x, cache["k_pages"], cache["v_pages"]),
+        (params["blocks"], jnp.arange(n_blocks, dtype=jnp.int32), h_all,
+         t_all))
     rec = {"h": rec["h"].at[1:].set(jnp.moveaxis(states, 0, 1)),
            "conv": rec["conv"].at[1:].set(jnp.moveaxis(tails, 0, 1))}
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)

@@ -411,10 +411,12 @@ class PagedKVCache:
     def device_tables(self):
         """(page_table, seq_lens) as device arrays for the jitted step.
 
-        Copies, not views: on CPU ``jnp.asarray`` may alias the numpy
-        buffer zero-copy, and the allocator mutates these arrays while the
-        dispatched step is still reading them asynchronously."""
-        return jnp.array(self.page_table), jnp.array(self.seq_lens)
+        Host copies, not views: the CPU backend may read a numpy buffer
+        zero-copy after the dispatch returns (``jnp.array`` of a numpy
+        array makes no host copy), and the allocator mutates these arrays
+        while the dispatched step is still reading them asynchronously."""
+        return (jnp.asarray(self.page_table.copy()),
+                jnp.asarray(self.seq_lens.copy()))
 
     # ------------------------------------------------------------------ stats
     def _mark_usage(self):
@@ -461,9 +463,8 @@ class PagedKVCache:
         """Device bytes one page costs across every attention layer (K and
         V both). 0 for attention-free stacks, whose pools have zero
         layers."""
-        k = self.pool["k_pages"]  # (L, P, ps, K, Dh) x2 for k and v
-        per_token = k.shape[0] * k.shape[3] * k.shape[4] * 2 * k.dtype.itemsize
-        return per_token * self.page_size
+        k = self.pool["k_pages"]  # (L, P, K, ps, Dh) x2 for k and v
+        return 2 * (k.size // k.shape[1]) * k.dtype.itemsize
 
     @property
     def high_water_bytes(self) -> int:

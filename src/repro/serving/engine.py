@@ -763,8 +763,9 @@ class ContinuousEngine:
         Pools are donated — updated in place, not copied."""
         L, _, Spad, K, D = ks.shape
         n = page_ids.shape[0]
-        ksr = ks[:, 0].reshape(L, n, Spad // n, K, D)
-        vsr = vs[:, 0].reshape(L, n, Spad // n, K, D)
+        # (L, Spad, K, D) -> the pool's per-page (L, n, K, ps, D) layout
+        ksr = ks[:, 0].reshape(L, n, Spad // n, K, D).swapaxes(2, 3)
+        vsr = vs[:, 0].reshape(L, n, Spad // n, K, D).swapaxes(2, 3)
         return (k_pool.at[:, page_ids].set(ksr),
                 v_pool.at[:, page_ids].set(vsr))
 
@@ -1526,8 +1527,9 @@ class ContinuousEngine:
             nxt, logits, kp, vp = self._draft_decode_fn(
                 self.draft_params, self.draft_cache.pool["k_pages"],
                 self.draft_cache.pool["v_pages"],
-                jnp.array(inputs[:, None]), pt, sl, jnp.asarray(active),
-                self._next_key(), jnp.array(self._temps), bound)
+                jnp.asarray(inputs[:, None].copy()), pt, sl,
+                jnp.asarray(active), self._next_key(),
+                jnp.asarray(self._temps.copy()), bound)
             self.draft_cache.pool = {"k_pages": kp, "v_pages": vp}
             self.draft_cache.seq_lens[act] += 1
             self.stats.draft_steps += 1
@@ -1683,14 +1685,15 @@ class ContinuousEngine:
                 self._decode_bounds.add((bound, wstart))
                 self.stats.decode_compiles += 1
             rec = self.rstate.state if self.rstate is not None else None
-            # jnp.array (copy): _next_in is mutated below while the
-            # dispatched step may still be reading it (CPU zero-copy alias)
+            # host copies: _next_in is mutated below while the dispatched
+            # step may still be reading it (CPU zero-copy alias; jnp.array
+            # of a numpy array makes no host copy)
             nxt, unc, kp, vp, rec = self._decode(
                 self.params, self.cache.pool["k_pages"],
                 self.cache.pool["v_pages"], rec,
-                jnp.array(self._next_in[:, None]), pt, sl,
+                jnp.asarray(self._next_in[:, None].copy()), pt, sl,
                 jnp.asarray(active), self._next_key(),
-                jnp.array(self._temps), bound, wstart)
+                jnp.asarray(self._temps.copy()), bound, wstart)
             self.cache.pool = {"k_pages": kp, "v_pages": vp}
             if self.rstate is not None:
                 self.rstate.state = rec

@@ -23,6 +23,7 @@ from repro.serving.scheduler import DECODING, PREFILLING
 from conftest import tiny_cfg
 
 NEG_INF = -1e30
+LAYER = 1   # the pool layer the kernels read; the references get kp[LAYER]
 
 
 def _bundle(seed=0, **kw):
@@ -32,11 +33,14 @@ def _bundle(seed=0, **kw):
 
 
 def _make_paged(rng, B, K, D, ps, MP, totals):
-    """Random page pool + a page table giving each request distinct pages
-    covering ``totals[b]`` tokens (page 0 reserved as scratch)."""
+    """Random stacked page pools (LAYER + 1, P, K, ps, D) + a page table
+    giving each request distinct pages covering ``totals[b]`` tokens (page 0
+    reserved as scratch)."""
     n_pages = 1 + sum(-(-int(t) // ps) for t in totals)
-    kp = jnp.asarray(rng.standard_normal((n_pages, ps, K, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, ps, K, D)), jnp.float32)
+    # LAYER + 1 layers: the kernels must read LAYER, not another layer
+    shape = (LAYER + 1, n_pages, K, ps, D)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     pt = np.zeros((B, MP), np.int32)
     nxt = 1
     for b in range(B):
@@ -60,9 +64,10 @@ def test_paged_prefill_kernel_matches_ref(G, ps, D, C, dtype):
     q = jnp.asarray(rng.standard_normal((B, K, C, G, D)), dtype) * (D ** -0.5)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, np.asarray(total))
     kp, vp = kp.astype(dtype), vp.astype(dtype)
-    out = paged_prefill_attention_gqa(q, kp, vp, pt, start, total,
+    out = paged_prefill_attention_gqa(q, kp, vp, LAYER, pt, start, total,
                                       interpret=True)
-    ref = paged_prefill_attention_ref(q, kp, vp, pt, start, total)
+    ref = paged_prefill_attention_ref(q, kp[LAYER], vp[LAYER], pt, start,
+                                      total)
     tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \
         else dict(rtol=3e-5, atol=3e-5)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -81,11 +86,12 @@ def test_paged_prefill_ref_matches_dense_causal_oracle():
     q = jnp.asarray(rng.standard_normal((B, K, C, G, D)), jnp.float32) \
         * (D ** -0.5)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, total)
+    kp, vp = kp[LAYER], vp[LAYER]
     out = paged_prefill_attention_ref(q, kp, vp, pt, jnp.asarray(start),
                                       jnp.asarray(total))
     S = MP * ps
-    kd = jnp.moveaxis(kp[pt], 3, 1).reshape(B, K, S, D)
-    vd = jnp.moveaxis(vp[pt], 3, 1).reshape(B, K, S, D)
+    kd = jnp.moveaxis(kp[pt], 2, 1).reshape(B, K, S, D)
+    vd = jnp.moveaxis(vp[pt], 2, 1).reshape(B, K, S, D)
     s = jnp.einsum("bkcgd,bksd->bkcgs", q, kd).astype(jnp.float32)
     qpos = jnp.asarray(start)[:, None] + jnp.arange(C)     # (B, C)
     valid = jnp.arange(S)[None, None, :] <= qpos[:, :, None]
@@ -110,12 +116,12 @@ def test_paged_prefill_live_bound_matches_full_walk():
     q = jnp.asarray(rng.standard_normal((B, K, C, G, D)), jnp.float32) \
         * (D ** -0.5)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, np.asarray(total))
-    full = paged_prefill_attention_gqa(q, kp, vp, pt, start, total,
+    full = paged_prefill_attention_gqa(q, kp, vp, LAYER, pt, start, total,
                                        interpret=True)
-    bk = paged_prefill_attention_gqa(q, kp, vp, pt, start, total,
+    bk = paged_prefill_attention_gqa(q, kp, vp, LAYER, pt, start, total,
                                      pages_bound=bound, interpret=True)
-    br = paged_prefill_attention_ref(q, kp, vp, pt, start, total,
-                                     pages_bound=bound)
+    br = paged_prefill_attention_ref(q, kp[LAYER], vp[LAYER], pt, start,
+                                     total, pages_bound=bound)
     np.testing.assert_allclose(np.asarray(bk), np.asarray(full),
                                rtol=3e-5, atol=3e-5)
     np.testing.assert_allclose(np.asarray(br), np.asarray(full),
@@ -135,9 +141,10 @@ def test_paged_prefill_ops_layout():
     q = jnp.asarray(rng.standard_normal((B, C, H, D)), jnp.float32) \
         * (D ** -0.5)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, np.asarray(total))
-    out = ppa_ops.paged_prefill_attention(q, kp, vp, pt, start, total)
+    out = ppa_ops.paged_prefill_attention(q, kp, vp, LAYER, pt, start, total)
     qg = jnp.transpose(q.reshape(B, C, K, G, D), (0, 2, 1, 3, 4))
-    ref = paged_prefill_attention_ref(qg, kp, vp, pt, start, total)
+    ref = paged_prefill_attention_ref(qg, kp[LAYER], vp[LAYER], pt, start,
+                                      total)
     ref = jnp.transpose(ref, (0, 2, 1, 3, 4)).reshape(B, C, H, D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-5, atol=3e-5)

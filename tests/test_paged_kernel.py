@@ -12,16 +12,22 @@ from repro.kernels.paged_decode_attention.kernel import \
 from repro.kernels.paged_decode_attention.ref import paged_decode_attention_ref
 
 
+LAYER = 1   # the pool layer the kernels read; the references get kp[LAYER]
+
+
 def _tol(dtype):
     return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \
         else dict(rtol=3e-5, atol=3e-5)
 
 
 def _make_paged(rng, B, K, D, ps, MP, lens):
-    """Random page pool + a page table giving each request distinct pages."""
+    """Random stacked page pools (LAYER + 1, P, K, ps, D) + a page table
+    giving each request distinct pages."""
     n_pages = 1 + sum(-(-int(l) // ps) for l in lens)  # page 0 reserved
-    kp = jnp.asarray(rng.standard_normal((n_pages, ps, K, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, ps, K, D)), jnp.float32)
+    # LAYER + 1 layers: the kernels must read LAYER, not another layer
+    shape = (LAYER + 1, n_pages, K, ps, D)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     pt = np.zeros((B, MP), np.int32)
     nxt = 1
     for b in range(B):
@@ -41,8 +47,9 @@ def test_paged_kernel_matches_ref(G, ps, D, dtype):
     q = jnp.asarray(rng.standard_normal((B, K, G, D)), dtype) * (D ** -0.5)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, np.asarray(lens))
     kp, vp = kp.astype(dtype), vp.astype(dtype)
-    out = paged_decode_attention_gqa(q, kp, vp, pt, lens, interpret=True)
-    ref = paged_decode_attention_ref(q, kp, vp, pt, lens)
+    out = paged_decode_attention_gqa(q, kp, vp, LAYER, pt, lens,
+                                     interpret=True)
+    ref = paged_decode_attention_ref(q, kp[LAYER], vp[LAYER], pt, lens)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
 
@@ -55,12 +62,13 @@ def test_paged_matches_dense_decode_oracle():
     lens = jnp.asarray([5, 29], jnp.int32)
     q = jnp.asarray(rng.standard_normal((B, K, G, D)), jnp.float32) * (D ** -0.5)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, np.asarray(lens))
-    out = paged_decode_attention_gqa(q, kp, vp, pt, lens, interpret=True)
+    out = paged_decode_attention_gqa(q, kp, vp, LAYER, pt, lens,
+                                     interpret=True)
 
-    # densify: (B, MP, ps, K, D) -> (B*K, S, D) with per-row validity
+    # densify: (B, MP, K, ps, D) -> (B*K, S, D) with per-row validity
     S = MP * ps
-    kd = jnp.moveaxis(kp[pt], 3, 1).reshape(B * K, S, D)
-    vd = jnp.moveaxis(vp[pt], 3, 1).reshape(B * K, S, D)
+    kd = jnp.moveaxis(kp[LAYER][pt], 2, 1).reshape(B * K, S, D)
+    vd = jnp.moveaxis(vp[LAYER][pt], 2, 1).reshape(B * K, S, D)
     valid = (jnp.arange(S)[None] < lens[:, None]).astype(jnp.int8)
     valid = jnp.repeat(valid, K, axis=0)
     ref = da_ref.decode_attention_ref(q.reshape(B * K, G, D), kd, vd, valid)
@@ -76,8 +84,9 @@ def test_paged_ops_layout():
     lens = jnp.asarray([7, 20], jnp.int32)
     q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32) * (D ** -0.5)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, np.asarray(lens))
-    out = pda_ops.paged_decode_attention(q, kp, vp, pt, lens)
-    ref = paged_decode_attention_ref(q.reshape(B, K, G, D), kp, vp, pt, lens)
+    out = pda_ops.paged_decode_attention(q, kp, vp, LAYER, pt, lens)
+    ref = paged_decode_attention_ref(q.reshape(B, K, G, D), kp[LAYER],
+                                     vp[LAYER], pt, lens)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref).reshape(B, H, D),
                                rtol=3e-5, atol=3e-5)
@@ -95,18 +104,20 @@ def test_paged_kernel_live_bound_matches_full_walk(bound):
     q = jnp.asarray(rng.standard_normal((B, K, G, D)), jnp.float32) \
         * (D ** -0.5)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, np.asarray(lens))
-    full = paged_decode_attention_gqa(q, kp, vp, pt, lens, interpret=True)
-    bk = paged_decode_attention_gqa(q, kp, vp, pt, lens, pages_bound=bound,
-                                    interpret=True)
-    br = paged_decode_attention_ref(q, kp, vp, pt, lens, pages_bound=bound)
+    full = paged_decode_attention_gqa(q, kp, vp, LAYER, pt, lens,
+                                      interpret=True)
+    bk = paged_decode_attention_gqa(q, kp, vp, LAYER, pt, lens,
+                                    pages_bound=bound, interpret=True)
+    br = paged_decode_attention_ref(q, kp[LAYER], vp[LAYER], pt, lens,
+                                    pages_bound=bound)
     np.testing.assert_allclose(np.asarray(bk), np.asarray(full),
                                rtol=3e-5, atol=3e-5)
     np.testing.assert_allclose(np.asarray(br), np.asarray(full),
                                rtol=3e-5, atol=3e-5)
     # the ops wrapper threads the bound through too
     H = K * G
-    ob = pda_ops.paged_decode_attention(q.reshape(B, H, D), kp, vp, pt,
-                                        lens, pages_bound=bound)
+    ob = pda_ops.paged_decode_attention(q.reshape(B, H, D), kp, vp, LAYER,
+                                        pt, lens, pages_bound=bound)
     np.testing.assert_allclose(np.asarray(ob),
                                np.asarray(full).reshape(B, H, D),
                                rtol=3e-5, atol=3e-5)
@@ -120,10 +131,12 @@ def test_paged_masks_scratch_page_reads():
     lens = jnp.asarray([3], jnp.int32)
     q = jnp.asarray(rng.standard_normal((B, K, G, D)), jnp.float32)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, np.asarray(lens))
-    out1 = paged_decode_attention_gqa(q, kp, vp, pt, lens, interpret=True)
+    out1 = paged_decode_attention_gqa(q, kp, vp, LAYER, pt, lens,
+                                      interpret=True)
     # poison the scratch page with huge values
-    kp2 = kp.at[0].set(100.0)
-    vp2 = vp.at[0].set(-100.0)
-    out2 = paged_decode_attention_gqa(q, kp2, vp2, pt, lens, interpret=True)
+    kp2 = kp.at[LAYER, 0].set(100.0)
+    vp2 = vp.at[LAYER, 0].set(-100.0)
+    out2 = paged_decode_attention_gqa(q, kp2, vp2, LAYER, pt, lens,
+                                      interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-6, atol=1e-6)

@@ -77,7 +77,8 @@ cfg = ArchConfig("mini", "moe", 4, 64, 4, 2, 128, 512, head_dim=16,
                  n_experts=4, top_k=2, dtype="bfloat16", vocab_pad_multiple=64,
                  attn_chunk=64)
 shape = InputShape("mini", 128, 16, "train")
-mesh = jax.make_mesh((4, 2), ("data", "model"), devices=jax.devices())
+mesh = jax.make_mesh((4, 2), ("data", "model"), devices=jax.devices(),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 bundle = build_model(cfg)
 p_specs = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
 b_specs = input_specs(cfg, shape)
@@ -115,7 +116,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.models.attention import decode_attention, init_attention
 from repro.models.config import ArchConfig
 from repro.sharding.context import flash_decode
-mesh = jax.make_mesh((2, 4), ("data", "model"), devices=jax.devices())
+mesh = jax.make_mesh((2, 4), ("data", "model"), devices=jax.devices(),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = ArchConfig("t", "dense", 2, 64, 4, 2, 0, 256, head_dim=16, attn_chunk=8)
 p = init_attention(jax.random.PRNGKey(0), cfg)
 B, S = 4, 32

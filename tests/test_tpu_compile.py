@@ -1,0 +1,119 @@
+"""Ahead-of-time compiles of the serving path's Pallas kernels for a
+described TPU v5e, at the widths of the published tiers the chip smoke
+serves (gemma3-4b attention, mamba2-130m SSD). Interpret mode never checks
+TPU block tiling; the TPU compiler here does, with no chip attached. Each
+test asserts the kernel survived as a ``tpu_custom_call``.
+
+The topology is described inside a module-scoped fixture (never at import):
+only one process at a time may load the TPU compiler library, and every
+xdist worker imports this module.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.decode_attention.kernel import decode_attention_gqa
+from repro.kernels.flash_attention.kernel import flash_attention_bhsd
+from repro.kernels.paged_decode_attention.kernel import \
+    paged_decode_attention_gqa
+from repro.kernels.paged_prefill_attention.kernel import \
+    paged_prefill_attention_gqa
+from repro.kernels.ssd_scan.kernel import ssd_chunk_scan
+
+# gemma3-4b serving geometry: 34 attention layers, 8 slots, 4 KV heads x 2
+# query heads each, head_dim 256, 16-token pages, 2048-token slots -> 128
+# pages per slot. The kernels read one layer (a traced scalar, as under the
+# serving path's layer scan) of the whole stacked pool.
+L, B, K, G, D, PS, MP = 34, 8, 4, 2, 256, 16, 128
+POOL = (L, 1 + B * MP, K, PS, D)
+WINDOW = 1024
+# mamba2-130m SSD geometry: 24 heads of 64 channels, state 128
+SSD_H, SSD_P, SSD_N = 24, 64, 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler library can be loaded here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compiles for a described chip are written to a persistent cache but
+    # cannot be read back without one; keep the cache out of these tests
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel_compiles(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("pages_bound,pages_start,window", [
+    (None, 0, 0), (2, 0, 0), (MP, MP // 2, WINDOW)])
+def test_paged_decode_compiles_gemma3(one_chip, pages_bound, pages_start,
+                                      window):
+    def fn(q, kp, vp, layer, pt, sl):
+        return paged_decode_attention_gqa(
+            q, kp, vp, layer, pt, sl, pages_bound=pages_bound,
+            pages_start=pages_start, window=window, interpret=False)
+    _assert_kernel_compiles(
+        fn, _spec(one_chip, (B, K, G, D)),
+        _spec(one_chip, POOL), _spec(one_chip, POOL),
+        _spec(one_chip, (), jnp.int32),
+        _spec(one_chip, (B, MP), jnp.int32), _spec(one_chip, (B,), jnp.int32))
+
+
+@pytest.mark.parametrize("C,pages_bound,pages_start,window", [
+    (16, 1, 0, 0), (8, None, 0, 0), (16, MP, MP // 2, WINDOW)])
+def test_paged_prefill_compiles_gemma3(one_chip, C, pages_bound, pages_start,
+                                       window):
+    def fn(q, kp, vp, layer, pt, start, total):
+        return paged_prefill_attention_gqa(
+            q, kp, vp, layer, pt, start, total, pages_bound=pages_bound,
+            pages_start=pages_start, window=window, interpret=False)
+    _assert_kernel_compiles(
+        fn, _spec(one_chip, (B, K, C, G, D)),
+        _spec(one_chip, POOL), _spec(one_chip, POOL),
+        _spec(one_chip, (), jnp.int32),
+        _spec(one_chip, (B, MP), jnp.int32), _spec(one_chip, (B,), jnp.int32),
+        _spec(one_chip, (B,), jnp.int32))
+
+
+@pytest.mark.parametrize("chunk", [16, 256])  # serving chunk, model chunk
+def test_ssd_chunk_scan_compiles_mamba2(one_chip, chunk):
+    bc = 2
+    _assert_kernel_compiles(
+        lambda *a: ssd_chunk_scan(*a, interpret=False),
+        _spec(one_chip, (bc, SSD_H, chunk, SSD_P), jnp.float32),
+        _spec(one_chip, (bc, SSD_H, chunk, 1), jnp.float32),
+        _spec(one_chip, (bc, SSD_H, chunk, 1), jnp.float32),
+        _spec(one_chip, (bc, chunk, SSD_N), jnp.float32),
+        _spec(one_chip, (bc, chunk, SSD_N), jnp.float32))
+
+
+def test_flash_attention_compiles(one_chip):
+    S = 1024
+    _assert_kernel_compiles(
+        lambda q, k, v: flash_attention_bhsd(q, k, v, causal=True,
+                                             window=WINDOW, interpret=False),
+        *[_spec(one_chip, (B * K * G, S, D)) for _ in range(3)])
+
+
+@pytest.mark.parametrize("S", [2048, 200])  # block-multiple, padded
+def test_dense_decode_compiles_gemma3(one_chip, S):
+    _assert_kernel_compiles(
+        lambda q, k, v, valid: decode_attention_gqa(
+            q, k, v, valid, bk=min(512, S), interpret=False),
+        _spec(one_chip, (B * K, G, D)), _spec(one_chip, (B * K, S, D)),
+        _spec(one_chip, (B * K, S, D)), _spec(one_chip, (B * K, S), jnp.int8))

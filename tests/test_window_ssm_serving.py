@@ -28,13 +28,18 @@ from repro.serving import ContinuousEngine, ContinuousPoolEngine, Engine
 from conftest import tiny_cfg
 
 NEG_INF = -1e30
+LAYER = 1   # the pool layer the kernels read; the references get kp[LAYER]
 
 
 # ------------------------------------------------------------------- kernels
 def _make_paged(rng, B, K, D, ps, MP, lens):
+    """Random stacked page pools (LAYER + 1, P, K, ps, D) + a page table
+    giving each request distinct pages."""
     n_pages = 1 + sum(-(-int(l) // ps) for l in lens)
-    kp = jnp.asarray(rng.standard_normal((n_pages, ps, K, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, ps, K, D)), jnp.float32)
+    # LAYER + 1 layers: the kernels must read LAYER, not another layer
+    shape = (LAYER + 1, n_pages, K, ps, D)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     pt = np.zeros((B, MP), np.int32)
     nxt = 1
     for b in range(B):
@@ -47,10 +52,10 @@ def _make_paged(rng, B, K, D, ps, MP, lens):
 def _dense_window_decode(q, kp, vp, pt, lens, window):
     """Dense windowed oracle: gather pages, mask by global position."""
     B, K, G, D = q.shape
-    ps = kp.shape[1]
+    ps = kp.shape[2]
     S = pt.shape[1] * ps
-    k = jnp.moveaxis(kp[pt], 3, 1).reshape(B, K, S, D)
-    v = jnp.moveaxis(vp[pt], 3, 1).reshape(B, K, S, D)
+    k = jnp.moveaxis(kp[pt], 2, 1).reshape(B, K, S, D)
+    v = jnp.moveaxis(vp[pt], 2, 1).reshape(B, K, S, D)
     s = jnp.einsum("bkgd,bksd->bkgs", q, k).astype(jnp.float32)
     kpos = jnp.arange(S)
     valid = (kpos[None] < lens[:, None]) \
@@ -68,12 +73,13 @@ def test_paged_decode_window_matches_dense_oracle(window):
     q = jnp.asarray(rng.standard_normal((B, K, G, D)), jnp.float32) \
         * (D ** -0.5)
     kp, vp, pt = _make_paged(rng, B, K, D, ps, MP, np.asarray(lens))
-    oracle = _dense_window_decode(q, kp, vp, pt, lens, window)
+    oracle = _dense_window_decode(q, kp[LAYER], vp[LAYER], pt, lens, window)
     first = min(max(0, int(l) - window) for l in np.asarray(lens)) // ps
     for pstart in sorted({0, first}):
-        out = paged_decode_attention_gqa(q, kp, vp, pt, lens, window=window,
-                                         pages_start=pstart, interpret=True)
-        ref = paged_decode_attention_ref(q, kp, vp, pt, lens,
+        out = paged_decode_attention_gqa(q, kp, vp, LAYER, pt, lens,
+                                         window=window, pages_start=pstart,
+                                         interpret=True)
+        ref = paged_decode_attention_ref(q, kp[LAYER], vp[LAYER], pt, lens,
                                          pages_start=pstart, window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                    rtol=3e-5, atol=3e-5)
@@ -97,8 +103,8 @@ def test_paged_prefill_window_matches_dense_oracle(window):
         * (D ** -0.5)
 
     S = MP * ps
-    k = jnp.moveaxis(kp[pt], 3, 1).reshape(B, K, S, D)
-    v = jnp.moveaxis(vp[pt], 3, 1).reshape(B, K, S, D)
+    k = jnp.moveaxis(kp[LAYER][pt], 2, 1).reshape(B, K, S, D)
+    v = jnp.moveaxis(vp[LAYER][pt], 2, 1).reshape(B, K, S, D)
     s = jnp.einsum("bkcgd,bksd->bkcgs", q, k).astype(jnp.float32)
     kpos = jnp.arange(S)
     qpos = start[:, None] + jnp.arange(C)
@@ -112,11 +118,12 @@ def test_paged_prefill_window_matches_dense_oracle(window):
     first = min(max(0, int(s0) - window + 1)
                 for s0 in np.asarray(start)) // ps
     for pstart in sorted({0, first}):
-        out = paged_prefill_attention_gqa(q, kp, vp, pt, start, total,
+        out = paged_prefill_attention_gqa(q, kp, vp, LAYER, pt, start, total,
                                           window=window, pages_start=pstart,
                                           interpret=True)
-        ref = paged_prefill_attention_ref(q, kp, vp, pt, start, total,
-                                          pages_start=pstart, window=window)
+        ref = paged_prefill_attention_ref(q, kp[LAYER], vp[LAYER], pt, start,
+                                          total, pages_start=pstart,
+                                          window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                    rtol=3e-5, atol=3e-5)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(oracle),
