@@ -40,6 +40,7 @@ from typing import Dict, Optional, Protocol, Sequence, Tuple, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.encoder import RouterConfig, router_encode
 
@@ -77,9 +78,16 @@ class HybridRouter:
 
 def route_scores_jit(rcfg: RouterConfig):
     """jit-friendly scoring fn for fusing into a serving step."""
-    def fn(params, tokens, mask):
+    def router_scores(params, tokens, mask):
         return jax.nn.sigmoid(router_encode(params, tokens, mask, rcfg))
-    return fn
+    return router_scores
+
+
+def _read_scores(router: HybridRouter, tokens, mask) -> np.ndarray:
+    """``router``'s scores of a padded batch, read back to the host."""
+    scores = router.scores(jnp.asarray(tokens), jnp.asarray(mask))
+    with TraceAnnotation("router.sync"):
+        return np.asarray(scores)
 
 
 # ------------------------------------------------------------------ policies
@@ -106,9 +114,9 @@ class ThresholdPolicy:
         return 2
 
     def decide(self, tokens, mask) -> Tuple[np.ndarray, np.ndarray]:
-        scores = np.asarray(self.router.scores(jnp.asarray(tokens),
-                                               jnp.asarray(mask)))
-        return np.where(scores >= self.router.threshold, 0, 1), scores
+        with TraceAnnotation("router.decide"):
+            scores = _read_scores(self.router, tokens, mask)
+            return np.where(scores >= self.router.threshold, 0, 1), scores
 
 
 @dataclasses.dataclass
@@ -170,25 +178,26 @@ class CascadePolicy:
                 else len(self.thresholds)) + 1
 
     def decide(self, tokens, mask) -> Tuple[np.ndarray, np.ndarray]:
-        tk, mk = jnp.asarray(tokens), jnp.asarray(mask)
-        if self.boundaries:
-            # first passing gate, cheapest first: walk the boundaries
-            # priciest-first so cheaper gates overwrite — the final value
-            # is the smallest b with score_b >= gate b's threshold
-            tier = np.full((len(tokens),), len(self.boundaries), np.int64)
-            scores0: Optional[np.ndarray] = None
-            for b in reversed(range(len(self.boundaries))):
-                gate = self.boundaries[b]
-                s = np.asarray(gate.scores(tk, mk))
-                tier = np.where(s >= gate.threshold, b, tier)
-                if b == 0:
-                    scores0 = s
-            return tier, scores0
-        scores = np.asarray(self.router.scores(tk, mk))
-        tier = np.zeros(scores.shape, np.int64)
-        for t in self.thresholds:
-            tier += scores < t
-        return tier, scores
+        with TraceAnnotation("router.decide"):
+            tk, mk = jnp.asarray(tokens), jnp.asarray(mask)
+            if self.boundaries:
+                # first passing gate, cheapest first: walk the boundaries
+                # priciest-first so cheaper gates overwrite — the final value
+                # is the smallest b with score_b >= gate b's threshold
+                tier = np.full((len(tokens),), len(self.boundaries), np.int64)
+                scores0: Optional[np.ndarray] = None
+                for b in reversed(range(len(self.boundaries))):
+                    gate = self.boundaries[b]
+                    s = _read_scores(gate, tk, mk)
+                    tier = np.where(s >= gate.threshold, b, tier)
+                    if b == 0:
+                        scores0 = s
+                return tier, scores0
+            scores = _read_scores(self.router, tk, mk)
+            tier = np.zeros(scores.shape, np.int64)
+            for t in self.thresholds:
+                tier += scores < t
+            return tier, scores
 
     @classmethod
     def from_frontier(cls, router: HybridRouter, frontier, n_tiers: int,
@@ -260,11 +269,12 @@ class QualityTargetPolicy:
         return np.stack([m(scores) for m in self.maps])
 
     def decide(self, tokens, mask) -> Tuple[np.ndarray, np.ndarray]:
-        scores = np.asarray(self.router.scores(jnp.asarray(tokens),
-                                               jnp.asarray(mask)))
-        ok = self.predicted_quality(scores) >= self.target
-        tier = np.where(ok.any(axis=0), ok.argmax(axis=0), self.n_tiers - 1)
-        return tier.astype(np.int64), scores
+        with TraceAnnotation("router.decide"):
+            scores = _read_scores(self.router, tokens, mask)
+            ok = self.predicted_quality(scores) >= self.target
+            tier = np.where(ok.any(axis=0), ok.argmax(axis=0),
+                            self.n_tiers - 1)
+            return tier.astype(np.int64), scores
 
     @classmethod
     def fit(cls, router: HybridRouter, scores: np.ndarray,

@@ -100,4 +100,5 @@ def decode_attention_gqa(q, k, v, valid, *, bk: int = 512,
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(q, k, v, valid.astype(jnp.int32)[:, None, :])

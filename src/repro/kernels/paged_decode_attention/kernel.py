@@ -167,5 +167,6 @@ def paged_decode_attention_gqa(q, k_pages, v_pages, layer, page_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q, k_pages, v_pages)

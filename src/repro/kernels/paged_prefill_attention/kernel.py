@@ -169,6 +169,7 @@ def paged_prefill_attention_gqa(q, k_pages, v_pages, layer, page_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, CG, D), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
       total.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
       q.reshape(B, K, CG, D), k_pages, v_pages)

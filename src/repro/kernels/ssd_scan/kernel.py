@@ -83,5 +83,6 @@ def ssd_chunk_scan(x, dt, dacum, B, C, *, interpret: bool | None = None):
             jax.ShapeDtypeStruct((bc * H, N, P), jnp.float32),
         ],
         interpret=interpret,
+        name="ssd_scan",
     )(xf, dtf, daf, Bf, Cf)
     return y.reshape(bc, H, l, P), st.reshape(bc, H, N, P)

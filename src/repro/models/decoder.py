@@ -111,10 +111,11 @@ def decoder_forward(params, batch, cfg):
 
 
 def _unembed(params, x, cfg):
-    if cfg.tie_embeddings:
-        return unembed(params["embed"], x, cfg.vocab_size)
     from .common import output_head
-    return output_head(params["head"], x, cfg.vocab_size)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            return unembed(params["embed"], x, cfg.vocab_size)
+        return output_head(params["head"], x, cfg.vocab_size)
 
 
 # -------------------------------------------------------------------- prefill

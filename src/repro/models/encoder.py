@@ -77,34 +77,35 @@ def init_router_encoder(key, cfg: RouterConfig) -> dict:
 
 def router_encode(params, tokens, mask, cfg: RouterConfig) -> jnp.ndarray:
     """tokens: (B, S) int32; mask: (B, S) 1=real token. Returns logits (B,)."""
-    B, S = tokens.shape
-    x = params["embed"][tokens]
-    pos = jnp.arange(S)
-    rel = pos[None, :] - pos[:, None]
-    buckets = _relative_bucket(rel, cfg.rel_buckets, cfg.rel_max_distance)
-    bias = params["rel_bias"][buckets]              # (S, S, H)
-    bias = jnp.transpose(bias, (2, 0, 1))[None]     # (1, H, S, S)
-    attn_mask = jnp.where(mask[:, None, None, :] > 0, 0.0, -1e30)
-    scale = cfg.head_dim ** -0.5
+    with jax.named_scope("router_encode"):
+        B, S = tokens.shape
+        x = params["embed"][tokens]
+        pos = jnp.arange(S)
+        rel = pos[None, :] - pos[:, None]
+        buckets = _relative_bucket(rel, cfg.rel_buckets, cfg.rel_max_distance)
+        bias = params["rel_bias"][buckets]              # (S, S, H)
+        bias = jnp.transpose(bias, (2, 0, 1))[None]     # (1, H, S, S)
+        attn_mask = jnp.where(mask[:, None, None, :] > 0, 0.0, -1e30)
+        scale = cfg.head_dim ** -0.5
 
-    def body(x, layer_p):
-        h = rmsnorm(layer_p["ln1"], x, cfg.norm_eps)
-        qkv = jnp.einsum("bsd,dthk->tbshk", h, layer_p["wqkv"])
-        q, k, v = qkv[0], qkv[1], qkv[2]
-        scores = jnp.einsum("bqhk,bshk->bhqs", q, k).astype(jnp.float32) * scale
-        scores = scores + bias.astype(jnp.float32) + attn_mask
-        w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        o = jnp.einsum("bhqs,bshk->bqhk", w, v).reshape(B, S, cfg.d_model)
-        x = x + o @ layer_p["wo"]
-        h = rmsnorm(layer_p["ln2"], x, cfg.norm_eps)
-        return x + mlp(layer_p["mlp"], h), None
+        def body(x, layer_p):
+            h = rmsnorm(layer_p["ln1"], x, cfg.norm_eps)
+            qkv = jnp.einsum("bsd,dthk->tbshk", h, layer_p["wqkv"])
+            q, k, v = qkv[0], qkv[1], qkv[2]
+            scores = jnp.einsum("bqhk,bshk->bhqs", q, k).astype(jnp.float32) * scale
+            scores = scores + bias.astype(jnp.float32) + attn_mask
+            w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+            o = jnp.einsum("bhqs,bshk->bqhk", w, v).reshape(B, S, cfg.d_model)
+            x = x + o @ layer_p["wo"]
+            h = rmsnorm(layer_p["ln2"], x, cfg.norm_eps)
+            return x + mlp(layer_p["mlp"], h), None
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
-    denom = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
-    pooled = (x * mask[..., None].astype(x.dtype)).sum(1) / denom.astype(x.dtype)
-    h = jnp.tanh(pooled @ params["head_w1"])
-    return (h @ params["head_w2"])[:, 0].astype(jnp.float32)
+        x, _ = jax.lax.scan(body, x, params["layers"])
+        x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
+        denom = jnp.maximum(mask.sum(-1, keepdims=True), 1.0)
+        pooled = (x * mask[..., None].astype(x.dtype)).sum(1) / denom.astype(x.dtype)
+        h = jnp.tanh(pooled @ params["head_w1"])
+        return (h @ params["head_w2"])[:, 0].astype(jnp.float32)
 
 
 def router_score(params, tokens, mask, cfg: RouterConfig) -> jnp.ndarray:

@@ -122,6 +122,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.data import tokenizer as tok
 from repro.models.model import ModelBundle
@@ -240,8 +241,8 @@ def make_engine(bundle: ModelBundle, params, **kw):
 class ContinuousStats:
     steps: int = 0               # steps that did any work (decode, prefill,
                                  # admission, or retirement) — prefill-only
-                                 # steps count too, so occupancy and wall_s
-                                 # agree on the denominator
+                                 # steps count too, so mean occupancy is
+                                 # over every step that did work
     decode_steps: int = 0        # steps that dispatched a decode kernel
     prefill_steps: int = 0       # steps that advanced at least one chunk
     prefill_only_steps: int = 0  # steps that prefilled but decoded nothing
@@ -290,7 +291,6 @@ class ContinuousStats:
     prefix_hit_tokens: int = 0   # prompt tokens whose prefill was skipped
     prefix_hit_pages: int = 0    # pages mapped read-only at admission
     cow_splits: int = 0          # copy-on-write page copies dispatched
-    wall_s: float = 0.0
 
     @property
     def mean_occupancy(self) -> float:
@@ -429,10 +429,12 @@ class ContinuousEngine:
                                   prefix_pages=prefix_cache)
         # COW split: device-copies one page's K/V src -> dst before a slot's
         # first write into a page it shares (donated pools, one trace)
-        self._copy_page = jax.jit(
-            lambda kp, vp, src, dst: (kp.at[:, dst].set(kp[:, src]),
-                                      vp.at[:, dst].set(vp[:, src])),
-            donate_argnums=(0, 1)) if prefix_cache else None
+        def copy_page(kp, vp, src, dst):
+            return (kp.at[:, dst].set(kp[:, src]),
+                    vp.at[:, dst].set(vp[:, src]))
+
+        self._copy_page = jax.jit(copy_page, donate_argnums=(0, 1)) \
+            if prefix_cache else None
         # SSM/hybrid stacks keep constant-size per-slot recurrent state
         # beside the page pool (serving.cache.RecurrentStatePool)
         self.rstate = RecurrentStatePool(bundle, n_slots) \
@@ -518,10 +520,12 @@ class ContinuousEngine:
         # on the (B_pack, 1, D) final-chunk hidden states — one
         # width-independent trace per pack-batch bucket, so non-final
         # chunks never pay the vocab projection
-        self._lm_head = jax.jit(bundle.lm_head) if self.prefill_chunk \
-            else None
+        def lm_head(params, x):
+            return bundle.lm_head(params, x)
+
+        self._lm_head = jax.jit(lm_head) if self.prefill_chunk else None
         # donated pools: scatter updates in place rather than copying
-        self._scatter = jax.jit(self._scatter_impl, donate_argnums=(0, 1))
+        self._scatter = jax.jit(self.scatter_prompt, donate_argnums=(0, 1))
         # per-slot sampling temperature: a request's own temperature (or the
         # engine default) lands here at admission, so one decode trace
         # serves any greedy/sampled mix (see generate._sample_rows)
@@ -550,14 +554,16 @@ class ContinuousEngine:
     def _build_decode(self):
         bundle = self.bundle
 
-        def fn(params, k_pages, v_pages, rec, token, page_table, seq_lens,
-               active, key, temps, pages_bound, window_start):
+        def decode_step(params, k_pages, v_pages, rec, token, page_table,
+                        seq_lens, active, key, temps, pages_bound,
+                        window_start):
             cache = {"k_pages": k_pages, "v_pages": v_pages}
             if rec is not None:
                 cache["rec"] = rec
-            logits, cache = bundle.decode_step_paged(
-                params, cache, token, page_table, seq_lens, active,
-                pages_bound=pages_bound, window_start=window_start)
+            with jax.named_scope("decode"):
+                logits, cache = bundle.decode_step_paged(
+                    params, cache, token, page_table, seq_lens, active,
+                    pages_bound=pages_bound, window_start=window_start)
             # per-slot temperatures (engine default unless the request set
             # its own): greedy rows take the argmax, sampled rows draw at
             # their own temperature — one trace for any mix
@@ -582,20 +588,23 @@ class ContinuousEngine:
         # (engine reassigns cache.pool / rstate.state from the outputs
         # immediately). pages_bound and window_start are static: one trace
         # per bucketed (live bound, window start) pair
-        return jax.jit(fn, donate_argnums=(1, 2, 3), static_argnums=(10, 11))
+        return jax.jit(decode_step, donate_argnums=(1, 2, 3),
+                       static_argnums=(10, 11))
 
     def _build_prefill_chunk(self):
         bundle = self.bundle
 
-        def fn(params, k_pages, v_pages, rec, tokens, page_table, start,
-               n_new, state_rows, pages_bound, window_start):
+        def prefill_chunk(params, k_pages, v_pages, rec, tokens, page_table,
+                          start, n_new, state_rows, pages_bound,
+                          window_start):
             cache = {"k_pages": k_pages, "v_pages": v_pages}
             if rec is not None:
                 cache["rec"] = rec
-            x_last, cache = bundle.prefill_paged_chunk(
-                params, cache, tokens, page_table, start, n_new,
-                pages_bound=pages_bound, window_start=window_start,
-                state_rows=state_rows)
+            with jax.named_scope("prefill"):
+                x_last, cache = bundle.prefill_paged_chunk(
+                    params, cache, tokens, page_table, start, n_new,
+                    pages_bound=pages_bound, window_start=window_start,
+                    state_rows=state_rows)
             return x_last, cache["k_pages"], cache["v_pages"], \
                 cache.get("rec")
 
@@ -603,7 +612,8 @@ class ContinuousEngine:
         # place — this is what retires the one-shot path's host _scatter —
         # and recurrent rows advance in place the same way. pages_bound and
         # window_start are static: one trace per bucketed pair
-        return jax.jit(fn, donate_argnums=(1, 2, 3), static_argnums=(9, 10))
+        return jax.jit(prefill_chunk, donate_argnums=(1, 2, 3),
+                       static_argnums=(9, 10))
 
     def _build_draft_prefill(self):
         """Jit the draft sibling's chunked prefill (the draft-cache mirror
@@ -611,15 +621,17 @@ class ContinuousEngine:
         samples a request's first token, the target does."""
         bundle = self.draft_bundle
 
-        def fn(params, k_pages, v_pages, tokens, page_table, start, n_new,
-               pages_bound, window_start):
+        def draft_prefill(params, k_pages, v_pages, tokens, page_table, start,
+                          n_new, pages_bound, window_start):
             cache = {"k_pages": k_pages, "v_pages": v_pages}
-            _, cache = bundle.prefill_paged_chunk(
-                params, cache, tokens, page_table, start, n_new,
-                pages_bound=pages_bound, window_start=window_start)
+            with jax.named_scope("prefill"):
+                _, cache = bundle.prefill_paged_chunk(
+                    params, cache, tokens, page_table, start, n_new,
+                    pages_bound=pages_bound, window_start=window_start)
             return cache["k_pages"], cache["v_pages"]
 
-        return jax.jit(fn, donate_argnums=(1, 2), static_argnums=(7, 8))
+        return jax.jit(draft_prefill, donate_argnums=(1, 2),
+                       static_argnums=(7, 8))
 
     def _build_draft_decode(self):
         """Jit one draft micro-step: decode + per-slot-temperature sample,
@@ -627,17 +639,19 @@ class ContinuousEngine:
         speculative acceptance needs the draft's proposal distribution)."""
         bundle = self.draft_bundle
 
-        def fn(params, k_pages, v_pages, token, page_table, seq_lens,
-               active, key, temps, pages_bound):
+        def draft_decode(params, k_pages, v_pages, token, page_table,
+                         seq_lens, active, key, temps, pages_bound):
             cache = {"k_pages": k_pages, "v_pages": v_pages}
-            logits, cache = bundle.decode_step_paged(
-                params, cache, token, page_table, seq_lens, active,
-                pages_bound=pages_bound, window_start=0)
+            with jax.named_scope("decode"):
+                logits, cache = bundle.decode_step_paged(
+                    params, cache, token, page_table, seq_lens, active,
+                    pages_bound=pages_bound, window_start=0)
             nxt = _sample_rows(key, logits, temps)
             nxt = jnp.where(active, nxt, jnp.int32(tok.PAD))
             return nxt, logits, cache["k_pages"], cache["v_pages"]
 
-        return jax.jit(fn, donate_argnums=(1, 2), static_argnums=(9,))
+        return jax.jit(draft_decode, donate_argnums=(1, 2),
+                       static_argnums=(9,))
 
     def _build_verify(self):
         """Jit the target's verify chunk: per-position logits for the whole
@@ -646,16 +660,18 @@ class ContinuousEngine:
         suffixes roll back via truncate_slot."""
         bundle = self.bundle
 
-        def fn(params, k_pages, v_pages, tokens, page_table, start, n_new,
-               pages_bound, window_start):
+        def verify_chunk(params, k_pages, v_pages, tokens, page_table, start,
+                         n_new, pages_bound, window_start):
             cache = {"k_pages": k_pages, "v_pages": v_pages}
-            x, cache = bundle.verify_paged_chunk(
-                params, cache, tokens, page_table, start, n_new,
-                pages_bound=pages_bound, window_start=window_start)
+            with jax.named_scope("verify"):
+                x, cache = bundle.verify_paged_chunk(
+                    params, cache, tokens, page_table, start, n_new,
+                    pages_bound=pages_bound, window_start=window_start)
             logits = bundle.lm_head(params, x)
             return logits, cache["k_pages"], cache["v_pages"]
 
-        return jax.jit(fn, donate_argnums=(1, 2), static_argnums=(7, 8))
+        return jax.jit(verify_chunk, donate_argnums=(1, 2),
+                       static_argnums=(7, 8))
 
     def attach_draft(self, bundle: ModelBundle, params,
                      gamma: int = 2) -> "ContinuousEngine":
@@ -757,7 +773,7 @@ class ContinuousEngine:
         return b if page else 0
 
     @staticmethod
-    def _scatter_impl(k_pool, v_pool, ks, vs, page_ids):
+    def scatter_prompt(k_pool, v_pool, ks, vs, page_ids):
         """Scatter a prefilled dense cache (L, 1, Spad, K, D) into the pool
         pages listed in ``page_ids`` (Spad = len(page_ids) * page_size).
         Pools are donated — updated in place, not copied."""
@@ -1238,57 +1254,59 @@ class ContinuousEngine:
         reserved scratch row. The page walk is bounded by the group's live
         maximum context (see _pages_bound); sliding-window runs may start
         it at the group's first live window page (see _window_start)."""
-        B = _bucket(len(group))
-        mp = self.cache.max_pages_per_slot
-        chunk = np.full((B, width), tok.PAD, np.int32)
-        # np copies throughout: the allocator mutates the page table while
-        # the dispatched kernel may still be reading it (CPU zero-copy alias)
-        pt = np.zeros((B, mp), np.int32)
-        start = np.zeros((B,), np.int32)
-        n_new = np.zeros((B,), np.int32)
-        rows = np.zeros((B,), np.int32)          # 0 = scratch state row
-        for i, (req, n) in enumerate(group):
-            chunk[i, :n] = req.serve_tokens[req.prefill_pos:
-                                            req.prefill_pos + n]
-            pt[i] = self.cache.page_table[req.slot]
-            start[i] = req.prefill_pos
-            n_new[i] = n
-            rows[i] = req.slot + 1
-        bound = self._pages_bound(int((start + n_new).max()))
-        # earliest position any REAL row's first chunk query can see under
-        # the window: min(start) - (window - 1)
-        w = self.bundle.cfg.sliding_window
-        wstart = self._window_start(
-            int(start[:len(group)].min()) - max(w - 1, 0))
-        if (B, width, bound, wstart) not in self._chunk_shapes:
-            self._chunk_shapes.add((B, width, bound, wstart))
-            self.stats.prefill_compiles += 1
-        rec = self.rstate.state if self.rstate is not None else None
-        x_last, kp, vp, rec = self._prefill_chunk_fn(
-            self.params, self.cache.pool["k_pages"],
-            self.cache.pool["v_pages"], rec, jnp.asarray(chunk),
-            jnp.asarray(pt), jnp.asarray(start), jnp.asarray(n_new),
-            jnp.asarray(rows), bound, wstart)
-        self.cache.pool = {"k_pages": kp, "v_pages": vp}
-        if self.rstate is not None:
-            self.rstate.state = rec
-        if self.draft_cache is not None:
-            # mirror the same chunk rows into the draft sibling's cache so
-            # every DECODING slot's draft context is ready to speculate the
-            # moment its prompt lands (draft pages were extended alongside
-            # the target's in _prefill_step). Draft stacks are pure global
-            # attention, so window_start is always 0
-            dpt = np.zeros((B, mp), np.int32)
+        with TraceAnnotation("engine.prefill.dispatch"):
+            B = _bucket(len(group))
+            mp = self.cache.max_pages_per_slot
+            chunk = np.full((B, width), tok.PAD, np.int32)
+            # np copies throughout: the allocator mutates the page table
+            # while the dispatched kernel may still be reading it (CPU
+            # zero-copy alias)
+            pt = np.zeros((B, mp), np.int32)
+            start = np.zeros((B,), np.int32)
+            n_new = np.zeros((B,), np.int32)
+            rows = np.zeros((B,), np.int32)          # 0 = scratch state row
             for i, (req, n) in enumerate(group):
-                dpt[i] = self.draft_cache.page_table[req.slot]
-            d_bound = self._pages_bound(int((start + n_new).max()),
-                                        cache=self.draft_cache)
-            kp, vp = self._draft_prefill_fn(
-                self.draft_params, self.draft_cache.pool["k_pages"],
-                self.draft_cache.pool["v_pages"], jnp.asarray(chunk),
-                jnp.asarray(dpt), jnp.asarray(start), jnp.asarray(n_new),
-                d_bound, 0)
-            self.draft_cache.pool = {"k_pages": kp, "v_pages": vp}
+                chunk[i, :n] = req.serve_tokens[req.prefill_pos:
+                                                req.prefill_pos + n]
+                pt[i] = self.cache.page_table[req.slot]
+                start[i] = req.prefill_pos
+                n_new[i] = n
+                rows[i] = req.slot + 1
+            bound = self._pages_bound(int((start + n_new).max()))
+            # earliest position any REAL row's first chunk query can see
+            # under the window: min(start) - (window - 1)
+            w = self.bundle.cfg.sliding_window
+            wstart = self._window_start(
+                int(start[:len(group)].min()) - max(w - 1, 0))
+            if (B, width, bound, wstart) not in self._chunk_shapes:
+                self._chunk_shapes.add((B, width, bound, wstart))
+                self.stats.prefill_compiles += 1
+            rec = self.rstate.state if self.rstate is not None else None
+            x_last, kp, vp, rec = self._prefill_chunk_fn(
+                self.params, self.cache.pool["k_pages"],
+                self.cache.pool["v_pages"], rec, jnp.asarray(chunk),
+                jnp.asarray(pt), jnp.asarray(start), jnp.asarray(n_new),
+                jnp.asarray(rows), bound, wstart)
+            self.cache.pool = {"k_pages": kp, "v_pages": vp}
+            if self.rstate is not None:
+                self.rstate.state = rec
+            if self.draft_cache is not None:
+                # mirror the same chunk rows into the draft sibling's cache
+                # so every DECODING slot's draft context is ready to
+                # speculate the moment its prompt lands (draft pages were
+                # extended alongside the target's in _prefill_step). Draft
+                # stacks are pure global attention, so window_start is 0
+                dpt = np.zeros((B, mp), np.int32)
+                for i, (req, n) in enumerate(group):
+                    dpt[i] = self.draft_cache.page_table[req.slot]
+                d_bound = self._pages_bound(int((start + n_new).max()),
+                                            cache=self.draft_cache)
+                kp, vp = self._draft_prefill_fn(
+                    self.draft_params, self.draft_cache.pool["k_pages"],
+                    self.draft_cache.pool["v_pages"], jnp.asarray(chunk),
+                    jnp.asarray(dpt), jnp.asarray(start), jnp.asarray(n_new),
+                    d_bound, 0)
+                self.draft_cache.pool = {"k_pages": kp, "v_pages": vp}
         self.stats.prefill_dispatches += 1
         finishing = []
         for i, (req, n) in enumerate(group):
@@ -1305,11 +1323,13 @@ class ContinuousEngine:
         if finishing:
             # one vocab projection per dispatch, and only when a prompt
             # finished: its row's logits sample that request's first token
-            logits = self._lm_head(self.params, x_last)[:, 0]
+            with TraceAnnotation("engine.prefill.sync"):
+                logits = self._lm_head(self.params, x_last)[:, 0]
             for i, req in finishing:
                 req.state = DECODING
-                first = int(_sample(self._next_key(), logits[i:i + 1],
-                                    self._req_temp(req))[0])
+                with TraceAnnotation("engine.prefill.sync"):
+                    first = int(_sample(self._next_key(), logits[i:i + 1],
+                                        self._req_temp(req))[0])
                 done = self._push_token(req, first)
                 if done is not None:
                     retired.append(done)
@@ -1632,18 +1652,92 @@ class ContinuousEngine:
         otherwise — and retire. ``spec=False`` forces every slot onto the
         plain decode path this step (the pool's degradation hook while a
         draft tier is stalled). Returns the requests completed during this
-        step, including any shed at submit since the last step."""
-        t0 = time.monotonic()
-        retired: List[Request] = self.drain_shed()
-        self._expire(retired)
-        progressed = self._admit(retired)
-        prefilled: List[int] = []
-        if self.prefill_chunk:
-            prefilled = self._prefill_step(retired)
-            progressed += len(prefilled)
-        spec_slots: List[int] = []
-        if self.spec_gamma and spec:
-            spec_slots = self._spec_round(retired)
+        step, including any shed at submit since the last step.
+
+        Each phase opens a profiler span (``engine.step`` around
+        ``engine.expire``, ``.admit``, ``.prefill``, ``.spec``,
+        ``.decode.prep``, ``.dispatch``, ``.sync``, ``.emit``): on the
+        device trace's clock while a profiler session runs, and nearly
+        free otherwise. Spans read no clock."""
+        with TraceAnnotation("engine.step"):
+            retired: List[Request] = self.drain_shed()
+            with TraceAnnotation("engine.expire"):
+                self._expire(retired)
+            with TraceAnnotation("engine.admit"):
+                progressed = self._admit(retired)
+            prefilled: List[int] = []
+            if self.prefill_chunk:
+                with TraceAnnotation("engine.prefill"):
+                    prefilled = self._prefill_step(retired)
+                progressed += len(prefilled)
+            spec_slots: List[int] = []
+            if self.spec_gamma and spec:
+                with TraceAnnotation("engine.spec"):
+                    spec_slots = self._spec_round(retired)
+            with TraceAnnotation("engine.decode.prep"):
+                steppable = self._grow_decode_slots(spec_slots, retired)
+                if steppable:
+                    args = self._decode_inputs(steppable)
+            if steppable:
+                with TraceAnnotation("engine.decode.dispatch"):
+                    nxt, unc, kp, vp, rec = self._decode(*args)
+                    self.cache.pool = {"k_pages": kp, "v_pages": vp}
+                    if self.rstate is not None:
+                        self.rstate.state = rec
+                    self.cache.seq_lens[steppable] += 1
+                with TraceAnnotation("engine.decode.sync"):
+                    nxt = np.asarray(nxt)
+                    if self.escalation is not None:
+                        unc = np.asarray(unc)
+                with TraceAnnotation("engine.decode.emit"):
+                    for slot in steppable:
+                        self.stats.decode_tokens += 1
+                        done = self._push_token(self.sched.running[slot],
+                                                int(nxt[slot]))
+                        if done is not None:
+                            retired.append(done)
+                    self.stats.decode_steps += 1
+                    if self.escalation is not None:
+                        self._watch_escalation(steppable, unc)
+            elif not spec_slots and not progressed and not retired \
+                    and (self.sched.running or self.sched.pending):
+                # nothing decoded, no prefill advanced, nothing admitted or
+                # retired, yet work remains. Resolution ladder: (1) pages
+                # held externally (hold_pages pressure) make the stall
+                # transient back-pressure — wait it out; (2) else evict a
+                # running slot if that can unwedge anyone
+                # (_resolve_stall); (3) otherwise occupied slots all
+                # stalled on pages, or a pending request can't admit into
+                # an otherwise idle pool — neither can ever resolve
+                if self.cache.held_pages:
+                    self.stats.stall_steps += 1
+                elif self._resolve_stall():
+                    progressed += 1
+                else:
+                    raise RuntimeError(
+                        "page pool deadlock: no slot could step and no "
+                        "request could admit or retire; provision more "
+                        "pages")
+            if steppable or spec_slots or progressed or retired:
+                # prefill-only steps count too: leaving them out of
+                # ``steps`` would overstate mean occupancy under heavy
+                # admission. Union, not sum: a slot whose final chunk
+                # landed this step decodes this same step and is busy once
+                self.stats.steps += 1
+                self.stats.occupancy_sum += len(
+                    set(steppable) | set(prefilled) | set(spec_slots))
+                if prefilled:
+                    self.stats.prefill_steps += 1
+                    if not steppable and not spec_slots:
+                        self.stats.prefill_only_steps += 1
+            return retired
+
+    def _grow_decode_slots(self, spec_slots: List[int],
+                           retired: List[Request]) -> List[int]:
+        """The DECODING slots that plain-decode this step, each with room
+        for the token it writes: a slot at its context cap retires, one
+        whose write page is shared and cannot be split waits a step.
+        ``spec_slots`` already emitted this step."""
         cap = self.cache.max_pages_per_slot * self.cache.page_size
         # decode growth must not eat pages promised to mid-prefill slots
         reserve = self._reserved_prefill_pages() if self.prefill_chunk else 0
@@ -1664,82 +1758,38 @@ class ContinuousEngine:
             # step — disabled (draft tier stalled), page pressure, or the
             # round would overrun its context cap
             self.stats.spec_fallbacks += 1
-        if steppable:
-            active = np.zeros((self.n_slots,), bool)
-            active[steppable] = True
-            pt, sl = self.cache.device_tables()
-            # live walk bound: every steppable slot's context — including
-            # the token this step writes — fits in ``bound`` pages, so the
-            # decode kernel's page walk scales with the live max context,
-            # not the engine-wide static table width. Inactive slots may
-            # exceed the bound; their output is garbage the step masks
-            bound = self._pages_bound(
-                int(self.cache.seq_lens[steppable].max()) + 1)
-            # sliding-window runs start their walk at the steppable slots'
-            # first live window page: the earliest in-window key of slot b
-            # is (seq_lens[b] + 1) - window
-            wstart = self._window_start(
-                int(self.cache.seq_lens[steppable].min()) + 1
-                - self.bundle.cfg.sliding_window)
-            if (bound, wstart) not in self._decode_bounds:
-                self._decode_bounds.add((bound, wstart))
-                self.stats.decode_compiles += 1
-            rec = self.rstate.state if self.rstate is not None else None
-            # host copies: _next_in is mutated below while the dispatched
-            # step may still be reading it (CPU zero-copy alias; jnp.array
-            # of a numpy array makes no host copy)
-            nxt, unc, kp, vp, rec = self._decode(
-                self.params, self.cache.pool["k_pages"],
+        return steppable
+
+    def _decode_inputs(self, steppable: List[int]) -> tuple:
+        """The decode step's arguments for ``steppable``, in its order."""
+        active = np.zeros((self.n_slots,), bool)
+        active[steppable] = True
+        pt, sl = self.cache.device_tables()
+        # live walk bound: every steppable slot's context — including the
+        # token this step writes — fits in ``bound`` pages, so the decode
+        # kernel's page walk scales with the live max context, not the
+        # engine-wide static table width. Inactive slots may exceed the
+        # bound; their output is garbage the step masks
+        bound = self._pages_bound(int(self.cache.seq_lens[steppable].max())
+                                  + 1)
+        # sliding-window runs start their walk at the steppable slots'
+        # first live window page: the earliest in-window key of slot b is
+        # (seq_lens[b] + 1) - window
+        wstart = self._window_start(
+            int(self.cache.seq_lens[steppable].min()) + 1
+            - self.bundle.cfg.sliding_window)
+        if (bound, wstart) not in self._decode_bounds:
+            self._decode_bounds.add((bound, wstart))
+            self.stats.decode_compiles += 1
+        rec = self.rstate.state if self.rstate is not None else None
+        # host copies: _next_in is mutated after the launch while the
+        # dispatched step may still be reading it (CPU zero-copy alias;
+        # jnp.array of a numpy array makes no host copy)
+        return (self.params, self.cache.pool["k_pages"],
                 self.cache.pool["v_pages"], rec,
                 jnp.asarray(self._next_in[:, None].copy()), pt, sl,
                 jnp.asarray(active), self._next_key(),
                 jnp.asarray(self._temps.copy()), bound, wstart)
-            self.cache.pool = {"k_pages": kp, "v_pages": vp}
-            if self.rstate is not None:
-                self.rstate.state = rec
-            self.cache.seq_lens[steppable] += 1
-            nxt = np.asarray(nxt)
-            for slot in steppable:
-                self.stats.decode_tokens += 1
-                done = self._push_token(self.sched.running[slot],
-                                        int(nxt[slot]))
-                if done is not None:
-                    retired.append(done)
-            self.stats.decode_steps += 1
-            if self.escalation is not None:
-                self._watch_escalation(steppable, np.asarray(unc))
-        elif not spec_slots and not progressed and not retired \
-                and (self.sched.running or self.sched.pending):
-            # nothing decoded, no prefill advanced, nothing admitted or
-            # retired, yet work remains. Resolution ladder: (1) pages held
-            # externally (hold_pages pressure) make the stall transient
-            # back-pressure — wait it out; (2) else evict a running slot
-            # if that can unwedge anyone (_resolve_stall); (3) otherwise
-            # occupied slots all stalled on pages, or a pending request
-            # can't admit into an otherwise idle pool — neither can ever
-            # resolve
-            if self.cache.held_pages:
-                self.stats.stall_steps += 1
-            elif self._resolve_stall():
-                progressed += 1
-            else:
-                raise RuntimeError(
-                    "page pool deadlock: no slot could step and no request "
-                    "could admit or retire; provision more pages")
-        if steppable or spec_slots or progressed or retired:
-            # prefill-only steps count too: they accrue wall_s, so leaving
-            # them out of ``steps`` would overstate mean occupancy under
-            # heavy admission. Union, not sum: a slot whose final chunk
-            # landed this step decodes this same step and is busy once
-            self.stats.steps += 1
-            self.stats.occupancy_sum += len(set(steppable) | set(prefilled)
-                                            | set(spec_slots))
-            if prefilled:
-                self.stats.prefill_steps += 1
-                if not steppable and not spec_slots:
-                    self.stats.prefill_only_steps += 1
-        self.stats.wall_s += time.monotonic() - t0
-        return retired
 
     def run(self) -> List[Request]:
         """Drain the queue; returns all requests retired during the drain

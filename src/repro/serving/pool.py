@@ -62,6 +62,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.routing import RoutingPolicy, TierMeter
 from repro.data import tokenizer as tok
@@ -273,23 +274,25 @@ class ContinuousPoolEngine:
             # to the priciest engine and only crash at retire time
             raise ValueError(f"policy returned tier indices outside "
                              f"[0, {self.n_tiers}): {np.unique(tier_idx)}")
-        reqs = []
-        for i, (row, tier) in enumerate(zip(query_tokens, tier_idx)):
-            eng = self.engines[int(tier)]
-            if trim_padding:
-                # trim to one past the last true mask position — a mask with
-                # interior holes has sum() < that, and trimming to sum()
-                # would drop real prompt tokens
-                nz = np.flatnonzero(np.asarray(query_mask[i]))
-                row = row[:int(nz[-1]) + 1] if len(nz) else row[:1]
-            cap = int(max_new_tokens[i]) if max_new_tokens is not None else None
-            temp = None if temperature is None else \
-                float(temperature[i] if np.ndim(temperature) else temperature)
-            req = eng.submit(row, max_new_tokens=cap, priority=priority,
-                             deadline_s=deadline_s, timeout_s=timeout_s,
-                             temperature=temp)
-            self._tier_of[req.rid] = int(tier)
-            reqs.append(req)
+        with TraceAnnotation("pool.enqueue"):
+            reqs = []
+            for i, (row, tier) in enumerate(zip(query_tokens, tier_idx)):
+                eng = self.engines[int(tier)]
+                if trim_padding:
+                    # trim to one past the last true mask position — a mask
+                    # with interior holes has sum() < that, and trimming to
+                    # sum() would drop real prompt tokens
+                    nz = np.flatnonzero(np.asarray(query_mask[i]))
+                    row = row[:int(nz[-1]) + 1] if len(nz) else row[:1]
+                cap = int(max_new_tokens[i]) if max_new_tokens is not None \
+                    else None
+                temp = None if temperature is None else float(
+                    temperature[i] if np.ndim(temperature) else temperature)
+                req = eng.submit(row, max_new_tokens=cap, priority=priority,
+                                 deadline_s=deadline_s, timeout_s=timeout_s,
+                                 temperature=temp)
+                self._tier_of[req.rid] = int(tier)
+                reqs.append(req)
         return reqs, tier_idx, scores
 
     def submit_to(self, tier: Union[int, str], tokens: np.ndarray,
@@ -400,9 +403,11 @@ class ContinuousPoolEngine:
             # escalated hand-offs drain even from a stalled tier: the
             # hand-off is host-side bookkeeping, and parking a cancelled
             # stream in a wedged tier's buffer would stall it twice
-            for req in eng.drain_escalated():
-                self._handoff(req)
-        self._account(retired)
+            with TraceAnnotation("pool.account"):
+                for req in eng.drain_escalated():
+                    self._handoff(req)
+        with TraceAnnotation("pool.account"):
+            self._account(retired)
         return retired
 
     def run(self) -> List[Request]:

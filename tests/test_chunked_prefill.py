@@ -462,8 +462,8 @@ def test_stalled_chunk_refunds_budget_to_skipped_slots():
 
 def test_prefill_only_steps_counted_in_occupancy():
     """Satellite: steps that only advanced prefill used to be invisible to
-    ``steps``/``occupancy_sum`` while still accruing wall_s, so
-    mean occupancy overstated under heavy admission. They now count."""
+    ``steps``/``occupancy_sum``, so mean occupancy overstated under heavy
+    admission. They now count."""
     cfg, m, p = _bundle()
     ce = ContinuousEngine(m, p, max_new_tokens=2, n_slots=2, page_size=8,
                           max_seq=64, prefill_chunk=4, prefill_budget=4)

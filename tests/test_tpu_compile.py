@@ -2,12 +2,15 @@
 described TPU v5e, at the widths of the published tiers the chip smoke
 serves (gemma3-4b attention, mamba2-130m SSD). Interpret mode never checks
 TPU block tiling; the TPU compiler here does, with no chip attached. Each
-test asserts the kernel survived as a ``tpu_custom_call``.
+test asserts the kernel survived as a ``tpu_custom_call``, and the kernels
+a device trace reads by name carry their ``pallas_call`` name.
 
 The topology is described inside a module-scoped fixture (never at import):
 only one process at a time may load the TPU compiler library, and every
 xdist worker imports this module.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -54,9 +57,14 @@ def _spec(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel_compiles(fn, *args):
+def _assert_kernel_compiles(fn, *args, name=None):
+    """``name``: the kernel's ``pallas_call`` name, which its custom call
+    must carry in the compiled text."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    if name is not None:
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", text), \
+            f"no custom call named {name!r}"
 
 
 @pytest.mark.parametrize("pages_bound,pages_start,window", [
@@ -71,7 +79,8 @@ def test_paged_decode_compiles_gemma3(one_chip, pages_bound, pages_start,
         fn, _spec(one_chip, (B, K, G, D)),
         _spec(one_chip, POOL), _spec(one_chip, POOL),
         _spec(one_chip, (), jnp.int32),
-        _spec(one_chip, (B, MP), jnp.int32), _spec(one_chip, (B,), jnp.int32))
+        _spec(one_chip, (B, MP), jnp.int32), _spec(one_chip, (B,), jnp.int32),
+        name="paged_decode_attention")
 
 
 @pytest.mark.parametrize("C,pages_bound,pages_start,window", [
@@ -87,7 +96,7 @@ def test_paged_prefill_compiles_gemma3(one_chip, C, pages_bound, pages_start,
         _spec(one_chip, POOL), _spec(one_chip, POOL),
         _spec(one_chip, (), jnp.int32),
         _spec(one_chip, (B, MP), jnp.int32), _spec(one_chip, (B,), jnp.int32),
-        _spec(one_chip, (B,), jnp.int32))
+        _spec(one_chip, (B,), jnp.int32), name="paged_prefill_attention")
 
 
 @pytest.mark.parametrize("chunk", [16, 256])  # serving chunk, model chunk
@@ -99,7 +108,7 @@ def test_ssd_chunk_scan_compiles_mamba2(one_chip, chunk):
         _spec(one_chip, (bc, SSD_H, chunk, 1), jnp.float32),
         _spec(one_chip, (bc, SSD_H, chunk, 1), jnp.float32),
         _spec(one_chip, (bc, chunk, SSD_N), jnp.float32),
-        _spec(one_chip, (bc, chunk, SSD_N), jnp.float32))
+        _spec(one_chip, (bc, chunk, SSD_N), jnp.float32), name="ssd_scan")
 
 
 def test_flash_attention_compiles(one_chip):
