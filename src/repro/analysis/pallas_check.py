@@ -56,6 +56,7 @@ _MAX_ENUM = 4096    # full grid enumeration cap; larger grids use corners
 class _BlockSpec:
     block_shape: Optional[Tuple[int, ...]] = None
     index_map: Optional[Callable] = None
+    memory_space: object = None
 
 
 @dataclasses.dataclass
@@ -273,7 +274,9 @@ def check_records(family: str, calls: Sequence[Captured],
                                                  call.out_shapes))]
         out_hits: Dict[int, Dict[Tuple, List[Tuple]]] = {}
         for name, spec, shape in specs:
-            if spec is None:
+            if spec is None or spec.block_shape is None:
+                # no blocks: the operand is handed over whole (pl.ANY
+                # leaves it in HBM for the kernel's own DMAs)
                 continue
             block = tuple(int(b) for b in (spec.block_shape or ()))
             if len(block) != len(shape):

@@ -253,11 +253,12 @@ def paged_decode_attention(params, x_t, k_pages, v_pages, layer, page_table,
     written into that layer in place and the kernel reads it there, so no
     layer's pool is sliced out; page_table: (B, MP); seq_lens: (B,) tokens
     already in each slot's cache (the new token lands at index seq_lens);
-    active: (B,) bool — inactive slots write to the reserved scratch page 0
-    and their output is garbage the engine masks. ``pages_bound``: static
-    live bound on the kernel's page walk (the engine computes it from its
-    seq_lens snapshot; every active slot's context must fit); None = the
-    full static page-table width. ``window``: this layer's static sliding
+    active: (B,) bool — inactive slots write to the reserved scratch page 0,
+    read no pages (their length is 0), and their output is masked by the
+    engine. ``pages_bound``: static live bound on the kernel's page walk
+    (the engine computes it from its seq_lens snapshot; every active
+    slot's context must fit); None = the full static page-table width.
+    ``window``: this layer's static sliding
     window (0 = global); ``pages_start``: static first walked page for
     window layers (every active slot's first in-window key must be
     ``>= pages_start * ps``; must be 0 when ``window`` is 0).
@@ -279,7 +280,8 @@ def paged_decode_attention(params, x_t, k_pages, v_pages, layer, page_table,
     at = (layer, page[:, None], heads, (pos % ps)[:, None])
     k_pages = k_pages.at[at].set(k_t[:, 0])
     v_pages = v_pages.at[at].set(v_t[:, 0])
-    lens = jnp.minimum(seq_lens + 1, cap)                 # incl. new token
+    # incl. the new token; 0 for idle slots, so the kernel skips them
+    lens = jnp.where(active, jnp.minimum(seq_lens + 1, cap), 0)
     scale = Dh ** -0.5
     qg = (q[:, 0] * scale).reshape(B, K, H // K, Dh)
     if cfg.use_pallas:

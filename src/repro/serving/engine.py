@@ -72,7 +72,10 @@ stay O(log max_pages)) — attention compute tracks the tokens actually
 resident the same way paged memory already does, instead of walking the
 engine-wide static ``max_pages_per_slot`` width with masked scratch-page
 reads. ``walk_bound="static"`` restores the full-width walk (the parity
-baseline).
+baseline). Within the bound the decode kernel copies and computes only
+each slot's live pages, in blocks, and slots the step masks read none;
+``ContinuousStats.decode_blocks_walked`` / ``decode_blocks_live`` count
+its grid steps and the live ones, from the same snapshot.
 
 Layer kinds beyond uniform-global attention (gemma3/jamba-style edge
 tiers): sliding-window layers mask the paged kernels by global position
@@ -125,6 +128,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.data import tokenizer as tok
+from repro.kernels.paged_decode_attention.kernel import walk_blocks
 from repro.models.model import ModelBundle
 from .cache import PagedKVCache, RecurrentStatePool
 from .generate import build_generate_fn, _sample, _sample_rows
@@ -257,6 +261,10 @@ class ContinuousStats:
                                  # prefill shapes traced
     decode_compiles: int = 0     # distinct (bound, wstart) decode page
                                  # walks traced
+    decode_blocks_walked: int = 0  # paged decode kernel grid steps, every
+                                   # attention layer of every decode step
+    decode_blocks_live: int = 0    # of those, the steps that copied and
+                                   # computed a block of a slot's live pages
     prefill_stalls: int = 0      # chunk extensions deferred for pool space
     occupancy_sum: int = 0       # busy slots (decoded + prefill-advanced)
                                  # summed over steps
@@ -507,6 +515,14 @@ class ContinuousEngine:
                                              # step/run result
         self._chunk_shapes: set = set()   # (batch, width, bound, wstart)
         self._decode_bounds: set = set()  # (bound, wstart) pairs traced
+        # attention layers per sliding window (0 = global): each decode
+        # step's kernel calls, for the walked/live block counters
+        cfg = bundle.cfg
+        self._attn_windows: Dict[int, int] = {}
+        for i in range(cfg.n_layers):
+            if cfg.layer_kind(i)["attn"]:
+                w = cfg.layer_window(i)
+                self._attn_windows[w] = self._attn_windows.get(w, 0) + 1
         self._next_in = np.full((n_slots,), tok.PAD, np.int32)
         self._seed = seed
         self._rng_salt = rng_salt
@@ -1781,6 +1797,7 @@ class ContinuousEngine:
         if (bound, wstart) not in self._decode_bounds:
             self._decode_bounds.add((bound, wstart))
             self.stats.decode_compiles += 1
+        self._count_decode_blocks(active, bound, wstart)
         rec = self.rstate.state if self.rstate is not None else None
         # host copies: _next_in is mutated after the launch while the
         # dispatched step may still be reading it (CPU zero-copy alias;
@@ -1790,6 +1807,25 @@ class ContinuousEngine:
                 jnp.asarray(self._next_in[:, None].copy()), pt, sl,
                 jnp.asarray(active), self._next_key(),
                 jnp.asarray(self._temps.copy()), bound, wstart)
+
+    def _count_decode_blocks(self, active: np.ndarray, bound: int,
+                             wstart: int) -> None:
+        """Add the decode kernel's walked and live blocks for this step,
+        from the lengths it sees: each active slot's context with the
+        token it writes, 0 for a slot the step masks."""
+        if not self._attn_windows:
+            return
+        cap = self.cache.max_pages_per_slot * self.cache.page_size
+        lens = np.where(active, np.minimum(self.cache.seq_lens + 1, cap), 0)
+        kp = self.cache.pool["k_pages"]
+        for window, n_layers in self._attn_windows.items():
+            walked, live = walk_blocks(
+                lens, page_size=self.cache.page_size, n_kv_heads=kp.shape[2],
+                head_dim=kp.shape[4], itemsize=kp.dtype.itemsize,
+                pages_bound=bound, pages_start=wstart if window else 0,
+                window=window)
+            self.stats.decode_blocks_walked += n_layers * walked
+            self.stats.decode_blocks_live += n_layers * live
 
     def run(self) -> List[Request]:
         """Drain the queue; returns all requests retired during the drain

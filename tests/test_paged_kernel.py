@@ -4,11 +4,12 @@ seq_lens (interpret mode on CPU)."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.decode_attention import ref as da_ref
 from repro.kernels.paged_decode_attention import ops as pda_ops
 from repro.kernels.paged_decode_attention.kernel import \
-    paged_decode_attention_gqa
+    block_pages, paged_decode_attention_gqa, walk_blocks
 from repro.kernels.paged_decode_attention.ref import paged_decode_attention_ref
 
 
@@ -140,3 +141,149 @@ def test_paged_masks_scratch_page_reads():
                                       interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-6, atol=1e-6)
+
+
+# --------------------------------------------------- block walk and skip
+# The TPU interpreter: uninitialised VMEM reads as NaN, and a read out of
+# bounds (of the page table or of a pool) raises.
+TPU_INTERPRET = pltpu.InterpretParams()
+
+
+def _check(q, kp, vp, pt, lens, dtype=jnp.float32, **walk):
+    out = paged_decode_attention_gqa(q, kp, vp, LAYER, jnp.asarray(pt),
+                                     jnp.asarray(lens, jnp.int32),
+                                     interpret=TPU_INTERPRET, **walk)
+    ref = paged_decode_attention_ref(q, kp[LAYER], vp[LAYER],
+                                     jnp.asarray(pt),
+                                     jnp.asarray(lens, jnp.int32), **walk)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **_tol(dtype))
+    return out
+
+
+@pytest.mark.parametrize("pages_bound", [None, 17])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernel_block_edges(pages_bound, dtype):
+    """Lengths of 0, 1, ps-1, ps, block-1, block and block+1 tokens, over
+    walks of 20 and 17 pages, neither a multiple of a block's 16: the last
+    block of the walk is partial, the last live block of a slot copies only
+    its live pages, and stale (NaN) buffer rows never reach the output."""
+    ps, K, G, D, MP = 16, 2, 2, 32, 20
+    walk = MP if pages_bound is None else pages_bound
+    ppb = block_pages(ps, K, D, jnp.dtype(dtype).itemsize, walk)
+    assert ppb == 16 and walk % ppb
+    blk = ppb * ps
+    lens = [0, 1, ps - 1, ps, blk - 1, blk, blk + 1]
+    rng = np.random.default_rng(31)
+    kp, vp, pt = _make_paged(rng, len(lens), K, D, ps, MP, lens)
+    kp, vp = kp.astype(dtype), vp.astype(dtype)
+    q = jnp.asarray(rng.standard_normal((len(lens), K, G, D)), dtype) \
+        * (D ** -0.5)
+    out = _check(q, kp, vp, pt, lens, dtype, pages_bound=pages_bound)
+    assert not np.asarray(out[0]).any()      # a slot of length 0: zeros
+
+
+def test_paged_kernel_reads_nothing_past_the_walk():
+    """A slot whose length runs past ``pages_bound`` (one the engine's step
+    masks) is clamped to the walk: handed a page table exactly the walk
+    wide, the kernel reads no entry past it (the interpreter would raise)
+    and attends to that slot's first ``pages_bound`` pages, as the
+    reference does."""
+    ps, K, G, D, bound = 16, 2, 2, 32, 20
+    lens = [40, 400, 300, 321]
+    rng = np.random.default_rng(5)
+    kp, vp, pt = _make_paged(rng, len(lens), K, D, ps, 25, lens)
+    q = jnp.asarray(rng.standard_normal((len(lens), K, G, D)), jnp.float32)
+    _check(q, kp, vp, pt[:, :bound], lens, pages_bound=bound)
+
+
+@pytest.mark.parametrize("window,lens,pages_start,pages_bound", [
+    # windows start inside blocks of a 56-page walk from page 8
+    (300, [520, 700, 777, 1000, 0], 8, 64),
+    # a walk shorter than a block
+    (40, [300, 290, 301], 8, 19),
+])
+def test_paged_kernel_window_walk(window, lens, pages_start, pages_bound):
+    """Window layers walk from ``pages_start > 0`` and skip, per slot, the
+    blocks wholly before the slot's window; a window that starts inside a
+    block masks that block's older keys."""
+    ps, K, G, D = 16, 2, 2, 32
+    rng = np.random.default_rng(window)
+    kp, vp, pt = _make_paged(rng, len(lens), K, D, ps, pages_bound, lens)
+    q = jnp.asarray(rng.standard_normal((len(lens), K, G, D)), jnp.float32)
+    _check(q, kp, vp, pt, lens, pages_bound=pages_bound,
+           pages_start=pages_start, window=window)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernel_ignores_poisoned_unowned_pages(dtype):
+    """Every page no slot owns, the scratch page 0 among them, holds NaN in
+    K and inf in V, and so do the rows past each slot's length in its last
+    page; table entries past a slot's length point at such pages. The
+    kernel must match the reference run on the same pools with the poison
+    zeroed: masked keys are selected away, never multiplied by zero."""
+    ps, K, G, D, MP = 16, 2, 2, 32, 24
+    lens = [1, 37, 256, 300, 0, 383]
+    rng = np.random.default_rng(17)
+    n_own = sum(-(-n // ps) for n in lens)
+    n_pages = 2 * n_own + 1
+    own = 1 + rng.permutation(n_pages - 1)[:n_own]       # scattered pages
+    free = np.setdiff1d(np.arange(n_pages), own)          # 0 included
+    pt = rng.choice(free, (len(lens), MP)).astype(np.int32)
+    live = np.zeros((n_pages, ps), bool)                  # real keys
+    nxt = 0
+    for b, n in enumerate(lens):
+        for i in range(-(-n // ps)):
+            pt[b, i] = own[nxt]
+            live[own[nxt], :min(ps, n - i * ps)] = True
+            nxt += 1
+    shape = (LAYER + 1, n_pages, K, ps, D)
+    kp = jnp.asarray(rng.standard_normal(shape), dtype)
+    vp = jnp.asarray(rng.standard_normal(shape), dtype)
+    mask = jnp.asarray(live)[None, :, None, :, None]
+    q = jnp.asarray(rng.standard_normal((len(lens), K, G, D)), dtype) \
+        * (D ** -0.5)
+    out = paged_decode_attention_gqa(
+        q, jnp.where(mask, kp, jnp.nan), jnp.where(mask, vp, jnp.inf),
+        LAYER, jnp.asarray(pt), jnp.asarray(lens, jnp.int32),
+        interpret=TPU_INTERPRET)
+    zero = jnp.zeros((), dtype)
+    ref = paged_decode_attention_ref(
+        q, jnp.where(mask, kp, zero)[LAYER], jnp.where(mask, vp, zero)[LAYER],
+        jnp.asarray(pt), jnp.asarray(lens, jnp.int32))
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("window,pages_start", [(0, 0), (300, 8)])
+def test_walk_blocks_counts_live_blocks(window, pages_start):
+    """``walk_blocks`` (the engine's counter) against a block-by-block
+    count of the blocks holding a key the query may attend, and the walked
+    count against the kernel's launch grid."""
+    from repro.analysis import pallas_check
+    ps, K, G, D, bound = 16, 2, 2, 32, 64
+    lens = [0, 1, 255, 256, 257, 520, 1000, 1100]
+    walked, live = walk_blocks(lens, page_size=ps, n_kv_heads=K, head_dim=D,
+                               itemsize=4, pages_bound=bound,
+                               pages_start=pages_start, window=window)
+    ppb = block_pages(ps, K, D, 4, bound - pages_start)
+    n_blocks = -(-(bound - pages_start) // ppb)
+    expect = 0
+    for n in lens:
+        for j in range(n_blocks):
+            first = pages_start + j * ppb
+            expect += any(
+                p * ps < n and (not window or (p + 1) * ps > n - window)
+                for p in range(first, min(first + ppb, bound)))
+    assert (walked, live) == (len(lens) * n_blocks, expect)
+    assert 0 < live < walked
+    with pallas_check.capture() as rec:
+        paged_decode_attention_gqa(
+            np.zeros((len(lens), K, G, D), np.float32),
+            np.zeros((LAYER + 1, 4, K, ps, D), np.float32),
+            np.zeros((LAYER + 1, 4, K, ps, D), np.float32), LAYER,
+            np.zeros((len(lens), bound), np.int32), np.asarray(lens, np.int32),
+            pages_bound=bound, pages_start=pages_start, window=window)
+    (call,) = rec.calls
+    assert call.grid == (len(lens), n_blocks)

@@ -9,7 +9,9 @@ The topology is described inside a module-scoped fixture (never at import):
 only one process at a time may load the TPU compiler library, and every
 xdist worker imports this module.
 """
+import ast
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,13 @@ from repro.kernels.ssd_scan.kernel import ssd_chunk_scan
 L, B, K, G, D, PS, MP = 34, 8, 4, 2, 256, 16, 128
 POOL = (L, 1 + B * MP, K, PS, D)
 WINDOW = 1024
+# Mistral-Large-2407 tier-1 serving geometry (the chip benchmark's tier 1):
+# a 2-layer stage, 16 slots of 5,120 tokens, 8 KV heads x 12 query heads,
+# head_dim 128, 16-token pages
+ML_B, ML_K, ML_G, ML_D, ML_MP = 16, 8, 12, 128, 320
+ML_POOL = (2, 1 + ML_B * ML_MP, ML_K, PS, ML_D)
+ROOFLINE_METRIC = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+                   / "metrics" / "paged_decode_roofline.py")
 # mamba2-130m SSD geometry: 24 heads of 64 channels, state 128
 SSD_H, SSD_P, SSD_N = 24, 64, 128
 
@@ -81,6 +90,46 @@ def test_paged_decode_compiles_gemma3(one_chip, pages_bound, pages_start,
         _spec(one_chip, (), jnp.int32),
         _spec(one_chip, (B, MP), jnp.int32), _spec(one_chip, (B,), jnp.int32),
         name="paged_decode_attention")
+
+
+def _roofline_pattern() -> str:
+    """The ``KERNEL`` pattern by which the benchmark's decode roofline
+    finds the kernel in a device trace, read from its file."""
+    tree = ast.parse(ROOFLINE_METRIC.read_text())
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and \
+                [t.id for t in node.targets] == ["KERNEL"]:
+            return ast.literal_eval(node.value)
+    raise AssertionError(f"no KERNEL in {ROOFLINE_METRIC}")
+
+
+@pytest.mark.parametrize("pages_bound", [128, 320])
+def test_paged_decode_compiles_mlarge_and_stays_findable(one_chip,
+                                                         pages_bound):
+    """The decode kernel at tier 1's geometry compiles, and its custom call,
+    printed with operand shapes as the device trace shows it, still matches
+    the roofline metric's pattern: page table, lengths and layer, then the
+    bf16 query."""
+    from jax._src.lib import xla_client
+
+    def fn(q, kp, vp, layer, pt, sl):
+        return paged_decode_attention_gqa(q, kp, vp, layer, pt, sl,
+                                          pages_bound=pages_bound,
+                                          interpret=False)
+    args = (_spec(one_chip, (ML_B, ML_K, ML_G, ML_D)),
+            _spec(one_chip, ML_POOL), _spec(one_chip, ML_POOL),
+            _spec(one_chip, (), jnp.int32),
+            _spec(one_chip, (ML_B, ML_MP), jnp.int32),
+            _spec(one_chip, (ML_B,), jnp.int32))
+    _assert_kernel_compiles(fn, *args, name="paged_decode_attention")
+    compiled = jax.jit(fn).lower(*args).compile()
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    text = "\n".join(m.to_string(opts)
+                     for m in compiled.runtime_executable().hlo_modules())
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert re.search(_roofline_pattern(), calls[0]), calls[0][:300]
 
 
 @pytest.mark.parametrize("C,pages_bound,pages_start,window", [
