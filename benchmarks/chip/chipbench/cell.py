@@ -12,7 +12,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
-from . import harness, spec, tracing, traffic
+from . import harness, phases, spec, tracing, traffic
 
 
 def compile_counter() -> dict:
@@ -158,11 +158,24 @@ def reduce_trace(trace_dir: str) -> dict:
     log(f"trace: {len(device)} device ops, {len(host)} host events; "
         f"device plane {seen.get('device_plane')}; lines "
         f"{ {k: v[:6] for k, v in seen.items() if k != 'device_plane'} }")
-    out = tracing.reduce(device, host, tracing.window_of(host))
+    return reduce_events(device, host, labels)
+
+
+def reduce_events(device: list, host: list, labels: dict) -> dict:
+    """The trace record: ``tracing.reduce`` of the window, each op's label,
+    and under ``phases`` the program's spans reduced (``phases.summary``)."""
+    window = tracing.window_of(host)
+    out = tracing.reduce(device, host, window)
     out["labels"] = {n: labels.get(n, n) for n in out["op_s"]}
+    out["phases"] = phases.summary(phases.reduce(device, host, window))
     log(f"trace: busy {out['busy_s']:.4f} s of {out['window_s']:.4f} s")
     for n, v in sorted(out["op_s"].items(), key=lambda x: -x[1])[:10]:
         log(f"trace op {v:.4f} s: {out['labels'][n][:400]}")
+    for n, v in out["phases"]["idle_by_span"].items():
+        log(f"idle {v:.4f} s under {n}")
+    for row in out["phases"]["spans"]:
+        log(f"span {row['tier']} {row['span']}: {row['count']} x, "
+            f"{row['total_s']:.4f} s, sync {row['sync_s']:.4f} s")
     return out
 
 

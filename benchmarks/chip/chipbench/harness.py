@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import reference, traffic, weights
+from .spec import tier_kind
 from .traffic import Arrival
 
 FINISHED = ("length", "eos")
@@ -107,8 +108,7 @@ class Run:
             cfg = arch_config(spec["model"])
             bundle = build_model(cfg)
             shapes = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
-            params = weights.make_params(shapes, weights.seed_key(seed, t),
-                                         embed_std(spec))
+            params = make_tier_params(spec, shapes, seed, t)
             eng = make_engine(bundle, params, **spec["engine"])
             if not isinstance(eng, ContinuousEngine):
                 raise RuntimeError(f"{spec['name']}: make_engine did not "
@@ -177,8 +177,7 @@ class Run:
             shapes = jax.eval_shape(tier.engine.bundle.init,
                                     jax.random.PRNGKey(0))
             tier.engine.params = self.params[t] = None
-            p = weights.make_params(shapes, weights.seed_key(seed, t),
-                                    embed_std(tier.spec))
+            p = make_tier_params(tier.spec, shapes, seed, t)
             tier.engine.params = self.params[t] = p
 
     def reset_pools(self) -> None:
@@ -206,9 +205,14 @@ class Run:
                 t.engine.rstate.state = None
 
 
-def embed_std(spec: dict) -> float:
-    """A tier's token-embedding scale (its file's ``weights.embed_std``)."""
-    return float(spec.get("weights", {}).get("embed_std", 0.02))
+def make_tier_params(spec: dict, shapes, seed: int, t: int):
+    """Tier ``t``'s weights from the seed, at its token-embedding scale
+    (its file's ``weights.embed_std``) and with its kind's stack axes."""
+    return weights.make_params(
+        shapes, weights.seed_key(seed, t),
+        float(spec.get("weights", {}).get("embed_std", 0.02)),
+        getattr(tier_kind(spec["reference"]), "stack_axes",
+                weights.stack_axes))
 
 
 def padded(tokens: np.ndarray):
@@ -408,9 +412,9 @@ def check(run: Run, loop: OpenLoop, seed: int, limits: dict,
             and s.req.finish_reason in FINISHED]
     out: Dict[str, dict] = {}
     for t, tier in enumerate(run.tiers):
-        kind = reference.REFERENCES[tier.spec["reference"]]
-        ref = kind(run.params[t], tier.spec["model"])
-        ctrl = kind(run.params[t], tier.spec["model"], quant=control) \
+        Ref = tier_kind(tier.spec["reference"]).Reference
+        ref = Ref(run.params[t], tier.spec["model"])
+        ctrl = Ref(run.params[t], tier.spec["model"], quant=control) \
             if control else None
         picked = _sample([s for s in ends if s.tier == t], PER_TIER, rng)
         if parts is not None and f"tier{t}" not in parts:

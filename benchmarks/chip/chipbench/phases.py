@@ -3,9 +3,9 @@ time goes, and which phase each idle gap of the device falls in.
 
 The serving path opens named spans (``router.*``, ``pool.*``, ``engine.*``)
 at its phase boundaries, inside the harness's own spans (``router``,
-``submit``, ``wait``, ``tierN.step``). Spans of one thread nest, so at each
-instant one span is the innermost. ``reduce(device_ops, host_events,
-window)`` takes the lists ``tracing.load`` reads and returns:
+``submit``, ``wait``, ``tierN.step``); ``tracing`` nests them.
+``reduce(device_ops, host_events, window)`` takes the lists
+``tracing.load`` reads and returns:
 
 * ``spans``: for each (enclosing ``tierN.step`` or None, span name), over
   the spans that start in the window: ``count``, ``total_s``, and
@@ -17,90 +17,20 @@ window)`` takes the lists ``tracing.load`` reads and returns:
 * ``step_idle_s``: idle seconds inside some ``engine.step`` span;
 * ``window_s``.
 
-Names are compared with any ``#...`` metadata suffix removed. Host events
-that neither the program nor the harness names (other threads, the XLA
-client, the ``window`` span) are left out.
+``summary`` turns that into the plain JSON the harness keeps as the trace
+record's ``phases``, which the ``step_host_ms.*`` and ``step_idle_share.*``
+metrics read.
 """
 from __future__ import annotations
 
-import bisect
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from .tracing import HOST_SPANS, Event, clip, merge
+from .tracing import (IDLE, Event, clip, idle_gaps, innermost, merge,
+                      name_gaps, nest)
 
 TIER_STEP = re.compile(r"tier(\d+)\.step")
-PROGRAM_PREFIXES = ("router.", "pool.", "engine.")
 ENGINE_STEP = "engine.step"
-IDLE = "host:idle"
-
-Nested = Tuple[str, float, float, Optional[int]]   # name, start, end, parent
-
-
-def base(name: str) -> str:
-    """A span's name without the metadata the profiler may append."""
-    return name.split("#", 1)[0]
-
-
-def is_span(name: str) -> bool:
-    return name in HOST_SPANS or name.startswith(PROGRAM_PREFIXES)
-
-
-def nest(host: Sequence[Event]) -> List[Nested]:
-    """The program's and the harness's spans in start order, each with the
-    index of the span it lies in (None at the top). A span that outlasts
-    its parent is cut at the parent's end."""
-    evs = sorted(((base(n), s, e) for n, s, e in host if is_span(base(n))),
-                 key=lambda x: (x[1], -x[2]))
-    out: List[Nested] = []
-    stack: List[int] = []
-    for n, s, e in evs:
-        while stack and out[stack[-1]][2] <= s:
-            stack.pop()
-        parent = stack[-1] if stack else None
-        if parent is not None:
-            e = min(e, out[parent][2])
-        out.append((n, s, e, parent))
-        stack.append(len(out) - 1)
-    return out
-
-
-def innermost(spans: List[Nested]) -> List[Tuple[float, float, int]]:
-    """Time cut into disjoint (start, end, span index) pieces, each piece
-    covered by that span and by none nested deeper; time no span covers
-    is left out."""
-    segs: List[Tuple[float, float, int]] = []
-    stack: List[int] = []
-    t = 0.0
-    for i, (_, s, _, _) in enumerate(spans):
-        while stack and spans[stack[-1]][2] <= s:
-            j = stack.pop()
-            if spans[j][2] > t:
-                segs.append((t, spans[j][2], j))
-            t = max(t, spans[j][2])
-        if stack and s > t:
-            segs.append((t, s, stack[-1]))
-        stack.append(i)
-        t = s
-    while stack:
-        j = stack.pop()
-        if spans[j][2] > t:
-            segs.append((t, spans[j][2], j))
-        t = max(t, spans[j][2])
-    return segs
-
-
-def idle_gaps(device_ops: Sequence[Event], window: Tuple[float, float]
-              ) -> List[Tuple[float, float]]:
-    """The window's intervals in which no device op runs."""
-    lo, hi = window
-    busy = merge((s, e) for _, s, e in clip(device_ops, lo, hi))
-    gaps, prev = [], lo
-    for s, e in busy + [(hi, hi)]:
-        if s > prev:
-            gaps.append((prev, s))
-        prev = max(prev, e)
-    return gaps
 
 
 def reduce(device_ops: Sequence[Event], host: Sequence[Event],
@@ -136,9 +66,9 @@ def reduce(device_ops: Sequence[Event], host: Sequence[Event],
         row["total_s"] += (e - s) / 1e9
         row["sync_s"] += sync[i] / 1e9
 
-    gaps = idle_gaps(device_ops, window)
+    gaps = idle_gaps(merge((s, e) for _, s, e in clip(device_ops, lo, hi)),
+                     window)
     segs = innermost(spans)
-    starts = [s for s, _, _ in segs]
     by_span: Dict[str, float] = {}
     step_idle = 0.0
     j = 0
@@ -158,15 +88,10 @@ def reduce(device_ops: Sequence[Event], host: Sequence[Event],
             k += 1
         by_span[IDLE] = by_span.get(IDLE, 0.0) + (g1 - g0) - covered
 
-    def name_at(t: float) -> str:
-        k = bisect.bisect_right(starts, t) - 1
-        return spans[segs[k][2]][0] if k >= 0 and t < segs[k][1] else IDLE
-
-    named = [(f"during {name_at((s + e) / 2)}", (e - s) / 1e9)
-             for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:top]]
     return {"window_s": (hi - lo) / 1e9, "spans": table,
             "idle_by_span": {n: v / 1e9 for n, v in by_span.items()},
-            "idle_gaps": named, "step_idle_s": step_idle / 1e9}
+            "idle_gaps": name_gaps(gaps, spans, segs, top),
+            "step_idle_s": step_idle / 1e9}
 
 
 def step_host_ms(ph: dict, tier: int) -> Optional[float]:

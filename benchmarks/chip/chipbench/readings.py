@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
-from . import stats, work
+from . import spec, stats, work
 
 
 def window(rec) -> Tuple[float, float]:
@@ -65,6 +65,12 @@ def trace_seconds(rec, match: str) -> Optional[float]:
     return s if s > 0 else None
 
 
+def phases(rec) -> Optional[dict]:
+    """The traced window's program spans, reduced (``phases.summary``)."""
+    tr = rec.get("trace")
+    return tr.get("phases") if tr else None
+
+
 def idle_share(rec) -> Optional[float]:
     tr = rec.get("trace")
     if tr is None or tr["window_s"] <= 0:
@@ -74,22 +80,22 @@ def idle_share(rec) -> Optional[float]:
 
 def kernel_roofline(rec, kind: str, match: str) -> Optional[float]:
     """Roofline share of a paged attention kernel over the traced window:
-    the work the window's dispatches needed over the kernel's device
-    seconds. ``kind`` is "decode" or "prefill"."""
+    the work the window's dispatches needed, over every tier whose kind
+    keeps K/V in the paged cache and over those layers of it, over the
+    kernel's device seconds. ``kind`` is "decode" or "prefill"."""
     secs = trace_seconds(rec, match)
     if secs is None or rec.get("work") is None or rec.get("peaks") is None:
         return None
+    count = work.paged_decode_work if kind == "decode" \
+        else work.paged_prefill_work
     flops = byts = 0.0
     for i, t in enumerate(rec["tiers"]):
-        if t["kind"] != "decoder":
+        layers = spec.tier_kind(t["kind"]).paged_layers(t["model"])
+        if not layers:
             continue
         items = rec["work"].get(f"tier{i}.{kind}", [])
-        if kind == "decode":
-            f, b = work.paged_decode_work(t["model"],
-                                          [c for _, cs in items for c in cs])
-        else:
-            f, b = work.paged_prefill_work(t["model"],
-                                           [c for _, cs in items for c in cs])
+        f, b = count(t["model"], [c for _, cs in items for c in cs],
+                     layers=layers)
         flops, byts = flops + f, byts + b
     if flops == 0:
         return None
@@ -98,8 +104,8 @@ def kernel_roofline(rec, kind: str, match: str) -> Optional[float]:
 
 def model_flops(rec) -> float:
     """Model FLOPs of all work the traced window carried: router scoring,
-    prefill chunks, decode tokens and the LM head of every token emitted,
-    both tiers."""
+    prefill chunks, decode tokens (each tier by its kind's
+    ``token_flops``) and the LM head of every token emitted, all tiers."""
     total = 0.0
     lo, hi = window(rec)
     for r in rec["requests"]:

@@ -1,8 +1,10 @@
-"""Plain references of the served models and the router, in float32.
+"""The float32 pieces the plain references share, and the router's.
 
-Each follows the architecture's published description, written again here
-in straightforward ``jax.numpy`` with ``precision="highest"`` matmuls: no
-kernel, cache, batching or chunking of the program's. It imports nothing
+Each served architecture's reference is in its kind file
+(``kinds/<kind>.py``) and is built from the pieces here. Each follows the
+architecture's published description, written again in straightforward
+``jax.numpy`` with ``precision="highest"`` matmuls: no kernel, cache,
+batching or chunking of the program's. It imports nothing
 of the program and reads only the weights the benchmark made. Departures
 from the published models are the program's own, and are named in the
 configuration files.
@@ -25,8 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
-SEQ_BLOCK = 1024     # sequence bucket of the decoder references
-Q_BLOCK = 256        # query rows per attention block
+SEQ_BLOCK = 1024     # sequence bucket of the served models' references
 FFN_BLOCK = 4096     # FFN columns per matmul block (bounds float32 temps)
 
 
@@ -80,41 +81,6 @@ def swiglu(h, p, quant):
     return linear(jnp.concatenate(cols, axis=1), p["w_out"], quant)
 
 
-# ------------------------------------------------------------ dense decoder
-@functools.partial(jax.jit, static_argnames=("i", "heads", "kv_heads",
-                                             "theta", "eps", "quant"))
-def _decoder_layer(layers, x, i, heads, kv_heads, theta, eps, quant):
-    """One pre-norm block: x + attn(norm(x)); x + swiglu(norm(x))."""
-    p = jax.tree_util.tree_map(lambda a: a[i], layers)
-    S, D = x.shape
-    h = rmsnorm(x, p["ln1"]["scale"], eps)
-    Dh = p["attn"]["wq"].shape[-1]
-    q = linear(h, p["attn"]["wq"].reshape(D, heads * Dh), quant)
-    k = linear(h, p["attn"]["wk"].reshape(D, kv_heads * Dh), quant)
-    v = linear(h, p["attn"]["wv"].reshape(D, kv_heads * Dh), quant)
-    pos = jnp.arange(S)
-    q = rope(q.reshape(S, heads, Dh), pos, theta) * Dh ** -0.5
-    k = rope(k.reshape(S, kv_heads, Dh), pos, theta)
-    v = v.reshape(S, kv_heads, Dh)
-    G = heads // kv_heads
-    kk = jnp.repeat(k, G, axis=1)          # query head h reads KV head h//G
-    vv = jnp.repeat(v, G, axis=1)
-
-    def block(qs):
-        q_i, q_pos = qs
-        s = jnp.einsum("qhd,khd->hqk", q_i, kk, precision=HI)
-        s = jnp.where(pos[None, None, :] <= q_pos[None, :, None], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("hqk,khd->qhd", w, vv, precision=HI)
-
-    nb = S // Q_BLOCK
-    o = jax.lax.map(block, (q.reshape(nb, Q_BLOCK, heads, Dh),
-                            pos.reshape(nb, Q_BLOCK)))
-    x = x + linear(o.reshape(S, heads * Dh), p["attn"]["wo"], quant)
-    h = rmsnorm(x, p["ln2"]["scale"], eps)
-    return x + swiglu(h, p["mlp"], quant)
-
-
 @functools.partial(jax.jit, static_argnames=("vocab", "eps", "quant",
                                              "tied"))
 def _lm_head(params, x, b, rows, tokens, vocab, eps, quant, tied):
@@ -141,32 +107,6 @@ def _pad_seqs(seqs, block: int) -> np.ndarray:
     return out
 
 
-class DecoderReference:
-    """A dense pre-norm decoder: RMSNorm, RoPE, grouped-query attention,
-    SwiGLU, untied LM head (Mistral's block)."""
-
-    def __init__(self, params, model: dict, quant: Optional[str] = None):
-        self.p, self.m, self.quant = params, model, quant
-
-    def gaps(self, seqs, rows, tokens) -> list:
-        """For each token sequence of ``seqs``, at its positions
-        ``rows[b]`` (row r predicts token r + 1): how far the logit of
-        ``tokens[b]`` lies below the best one, the best token and the best
-        logit (``head``). One sequence at a time: the attention of a long one
-        fills the chip."""
-        m = self.m
-        out = []
-        for seq, r, t in zip(seqs, rows, tokens):
-            toks = jnp.asarray(_pad_seqs([seq], SEQ_BLOCK)[0])
-            x = self.p["embed"]["table"][toks].astype(jnp.float32)
-            for i in range(m["n_layers"]):
-                x = _decoder_layer(self.p["layers"], x, i, m["n_heads"],
-                                   m["n_kv_heads"], float(m["rope_theta"]),
-                                   float(m["norm_eps"]), self.quant)
-            out += head(self.p, m, x[None], [r], [t], self.quant)
-        return out
-
-
 def head(params, m: dict, x, rows, tokens, quant) -> list:
     """For each sequence b of ``x`` (B, S, D): the gaps of ``tokens[b]``
     below the best logit at rows ``rows[b]``, the best tokens and the best
@@ -185,76 +125,6 @@ def head(params, m: dict, x, rows, tokens, quant) -> list:
         out.append((np.asarray(gap)[:n], np.asarray(best)[:n],
                     np.asarray(top)[:n]))
     return out
-
-
-# ------------------------------------------------------------------ mamba-2
-def _mamba_block(x, p, d_inner, state, headdim, eps, quant):
-    """One Mamba-2 block, x + mixer(norm(x)), as the plain recurrence
-    h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T, y_t = h_t C_t + D x_t."""
-    s = p["ssm"]
-    S = x.shape[0]
-    di, N, P = d_inner, state, headdim
-    H = di // P
-    u = linear(rmsnorm(x, p["ln"]["scale"], eps), s["w_in"], quant)
-    z, xbc, dt = u[:, :di], u[:, di:2 * di + 2 * N], u[:, 2 * di + 2 * N:]
-    cw = s["conv_w"].astype(jnp.float32)                  # (W, channels)
-    W = cw.shape[0]
-    padded = jnp.concatenate([jnp.zeros((W - 1, xbc.shape[1])), xbc], 0)
-    conv = sum(padded[j:j + S] * cw[j] for j in range(W))
-    xbc = jax.nn.silu(conv)
-    xs = xbc[:, :di].reshape(S, H, P)
-    Bm, Cm = xbc[:, di:di + N], xbc[:, di + N:]
-    dt = jax.nn.softplus(dt + s["dt_bias"].astype(jnp.float32))   # (S, H)
-    A = -jnp.exp(s["A_log"].astype(jnp.float32))
-
-    def step(h, inp):
-        x_t, b_t, c_t, dt_t = inp
-        h = jnp.exp(dt_t * A)[:, None, None] * h \
-            + (dt_t[:, None] * x_t)[:, :, None] * b_t[None, None, :]
-        return h, jnp.einsum("hpn,n->hp", h, c_t, precision=HI)
-
-    _, y = jax.lax.scan(step, jnp.zeros((H, P, N), jnp.float32),
-                        (xs, Bm, Cm, dt), unroll=8)
-    y = y + xs * s["D"].astype(jnp.float32)[None, :, None]
-    y = y.reshape(S, di) * jax.nn.silu(z)
-    y = rmsnorm(y, s["norm"]["scale"], eps)
-    return x + linear(y, s["w_out"], quant)
-
-
-@functools.partial(jax.jit, static_argnames=("d_inner", "state", "headdim",
-                                             "eps", "quant"))
-def _mamba_stack(layers, x, d_inner, state, headdim, eps, quant):
-    """Every block in turn over a batch of sequences ``x`` (B, S, D), one
-    compile for the whole stack."""
-    block = jax.vmap(_mamba_block, in_axes=(0, None, None, None, None, None,
-                                            None))
-
-    def body(x, p):
-        return block(x, p, d_inner, state, headdim, eps, quant), None
-    return jax.lax.scan(body, x, layers)[0]
-
-
-class MambaReference:
-    """Mamba-2 (SSD) language model: pre-norm Mamba-2 blocks, tied
-    embedding and LM head."""
-
-    def __init__(self, params, model: dict, quant: Optional[str] = None):
-        self.p, self.m, self.quant = params, model, quant
-
-    def gaps(self, seqs, rows, tokens) -> list:
-        """As ``DecoderReference.gaps``; all sequences in one batch, so
-        the recurrence's steps are shared."""
-        m = self.m
-        toks = jnp.asarray(_pad_seqs(seqs, SEQ_BLOCK))
-        x = self.p["embed"]["table"][toks].astype(jnp.float32)
-        x = _mamba_stack(self.p["layers"], x,
-                         m.get("ssm_expand", 2) * m["d_model"],
-                         m["ssm_state"], m.get("ssm_headdim", 64),
-                         float(m["norm_eps"]), self.quant)
-        return head(self.p, m, x, rows, tokens, self.quant)
-
-
-REFERENCES = {"decoder": DecoderReference, "mamba2": MambaReference}
 
 
 # ------------------------------------------------------------------- router

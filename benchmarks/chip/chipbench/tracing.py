@@ -5,18 +5,31 @@ Works on plain event lists so it can be checked on a small synthetic trace:
 ``reduce(device_ops, host_spans, window)`` with device ops as
 ``(name, start_ns, end_ns)`` and host spans as ``(name, start_ns, end_ns)``.
 ``load(path)`` reads those lists from an ``.xplane.pb`` file.
+
+The spans that name what the host was doing are the harness's own
+(``HOST_SPANS``) and the program's (``router.*``, ``pool.*``,
+``engine.*``). Spans of one thread nest, so at each instant one span is
+the innermost (``nest``, ``innermost``); an idle gap is named after the
+innermost span at its midpoint. Names are compared with any ``#...``
+metadata suffix removed; host events that neither the program nor the
+harness names (other threads, the XLA client, the ``window`` span) are
+left out.
 """
 from __future__ import annotations
 
+import bisect
 import re
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 Event = Tuple[str, float, float]          # (name, start_ns, end_ns)
+Nested = Tuple[str, float, float, Optional[int]]   # name, start, end, parent
 
-# the harness's own spans; an idle gap is named after the one it falls in
+# the harness's own spans, and the prefixes of the program's
 HOST_SPANS = ("router", "submit", "wait", "tier0.step", "tier1.step",
               "tier2.step", "tier3.step")
+PROGRAM_PREFIXES = ("router.", "pool.", "engine.")
 WINDOW_SPAN = "window"
+IDLE = "host:idle"
 
 
 def merge(intervals: Iterable[Tuple[float, float]]
@@ -38,12 +51,94 @@ def clip(events: Sequence[Event], lo: float, hi: float) -> List[Event]:
             if e > lo and s < hi]
 
 
+def idle_gaps(busy: List[Tuple[float, float]], window: Tuple[float, float]
+              ) -> List[Tuple[float, float]]:
+    """The window's intervals outside ``busy`` (sorted, disjoint, inside
+    the window): those in which no device op runs."""
+    lo, hi = window
+    gaps, prev = [], lo
+    for s, e in busy + [(hi, hi)]:
+        if s > prev:
+            gaps.append((prev, s))
+        prev = max(prev, e)
+    return gaps
+
+
+def base(name: str) -> str:
+    """A span's name without the metadata the profiler may append."""
+    return name.split("#", 1)[0]
+
+
+def is_span(name: str) -> bool:
+    return name in HOST_SPANS or name.startswith(PROGRAM_PREFIXES)
+
+
+def nest(host: Sequence[Event]) -> List[Nested]:
+    """The program's and the harness's spans in start order, each with the
+    index of the span it lies in (None at the top). A span that outlasts
+    its parent is cut at the parent's end."""
+    evs = sorted(((base(n), s, e) for n, s, e in host if is_span(base(n))),
+                 key=lambda x: (x[1], -x[2]))
+    out: List[Nested] = []
+    stack: List[int] = []
+    for n, s, e in evs:
+        while stack and out[stack[-1]][2] <= s:
+            stack.pop()
+        parent = stack[-1] if stack else None
+        if parent is not None:
+            e = min(e, out[parent][2])
+        out.append((n, s, e, parent))
+        stack.append(len(out) - 1)
+    return out
+
+
+def innermost(spans: List[Nested]) -> List[Tuple[float, float, int]]:
+    """Time cut into disjoint (start, end, span index) pieces, each piece
+    covered by that span and by none nested deeper; time no span covers
+    is left out."""
+    segs: List[Tuple[float, float, int]] = []
+    stack: List[int] = []
+    t = 0.0
+    for i, (_, s, _, _) in enumerate(spans):
+        while stack and spans[stack[-1]][2] <= s:
+            j = stack.pop()
+            if spans[j][2] > t:
+                segs.append((t, spans[j][2], j))
+            t = max(t, spans[j][2])
+        if stack and s > t:
+            segs.append((t, s, stack[-1]))
+        stack.append(i)
+        t = s
+    while stack:
+        j = stack.pop()
+        if spans[j][2] > t:
+            segs.append((t, spans[j][2], j))
+        t = max(t, spans[j][2])
+    return segs
+
+
+def name_gaps(gaps: List[Tuple[float, float]], spans: List[Nested],
+              segs: List[Tuple[float, float, int]], top: int
+              ) -> List[Tuple[str, float]]:
+    """The ``top`` longest gaps, longest first, as (``during <span>``,
+    seconds), each named after the innermost of ``spans`` (``segs``: their
+    ``innermost`` pieces) at its midpoint, ``host:idle`` where none is."""
+    starts = [s for s, _, _ in segs]
+
+    def name_at(t: float) -> str:
+        k = bisect.bisect_right(starts, t) - 1
+        return spans[segs[k][2]][0] if k >= 0 and t < segs[k][1] else IDLE
+
+    return [(f"during {name_at((s + e) / 2)}", (e - s) / 1e9)
+            for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:top]]
+
+
 def reduce(device_ops: Sequence[Event], host_spans: Sequence[Event],
            window: Tuple[float, float], top: int = 10) -> dict:
     """Busy seconds (union of device-op intervals inside the window), the
     device seconds of every op name, and the ``top`` longest idle gaps,
-    each named after the innermost harness span that covers its midpoint
-    (``host:idle`` when none does)."""
+    each named after the innermost harness or program span that covers
+    its midpoint (``host:idle`` when none does)."""
     lo, hi = window
     ops = clip(device_ops, lo, hi)
     busy = merge((s, e) for _, s, e in ops)
@@ -51,19 +146,8 @@ def reduce(device_ops: Sequence[Event], host_spans: Sequence[Event],
     by_name: Dict[str, float] = {}
     for n, s, e in ops:
         by_name[n] = by_name.get(n, 0.0) + (e - s)
-    gaps, prev = [], lo
-    for s, e in busy + [(hi, hi)]:
-        if s > prev:
-            gaps.append((prev, s))
-        prev = max(prev, e)
-    spans = [h for h in host_spans if h[0] in HOST_SPANS]
-    named = []
-    for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:top]:
-        mid = (s + e) / 2
-        covering = [h for h in spans if h[1] <= mid <= h[2]]
-        name = min(covering, key=lambda h: h[2] - h[1])[0] if covering \
-            else "host:idle"
-        named.append((f"during {name}", (e - s) / 1e9))
+    spans = nest(host_spans)
+    named = name_gaps(idle_gaps(busy, window), spans, innermost(spans), top)
     return {
         "window_s": (hi - lo) / 1e9,
         "busy_s": busy_ns / 1e9,

@@ -5,6 +5,11 @@ and takes nothing the program made. One jitted call fills every leaf of the
 program's parameter tree (shapes and dtypes from ``jax.eval_shape`` of its
 init) in the dtype it is served in. A leaf is filled by its name; a leaf
 with no rule is an error, so a change of the program's layout shows here.
+
+A matrix leaf is scaled by its fan-in, the first axis after the axes it
+stacks over (layers, positions in a period, experts). How many those are
+is the tier kind's ``stack_axes(names, shape)`` where the kind has one,
+else ``stack_axes`` here: one under ``layers``, none elsewhere.
 """
 from __future__ import annotations
 
@@ -17,9 +22,14 @@ def _names(path) -> list:
     return [getattr(k, "key", getattr(k, "idx", None)) for k in path]
 
 
-def _leaf(key, names, shape, dtype, embed_std):
+def stack_axes(names, shape) -> int:
+    """The default count of a leaf's stack axes: its layer axis, where it
+    lies under ``layers``."""
+    return 1 if "layers" in names else 0
+
+
+def _leaf(key, names, shape, dtype, embed_std, n_stack):
     name = names[-1]
-    stacked = "layers" in names
     if name == "table" or names == ["embed"]:
         return jax.random.truncated_normal(key, -2.0, 2.0, shape, dtype) \
             * jnp.asarray(embed_std, dtype)
@@ -40,24 +50,27 @@ def _leaf(key, names, shape, dtype, embed_std):
     if name == "conv_w":
         return jax.random.truncated_normal(key, -2.0, 2.0, shape, dtype) \
             * jnp.asarray(0.2, dtype)
-    if len(shape) >= 2 + stacked:
-        fan_in = shape[1] if stacked else shape[0]
+    if len(shape) >= 2 + n_stack:
+        fan_in = shape[n_stack]
         return jax.random.truncated_normal(key, -2.0, 2.0, shape, dtype) \
             * jnp.asarray(fan_in ** -0.5, dtype)
     raise ValueError(f"no weight rule for leaf {'/'.join(map(str, names))} "
                      f"{shape}")
 
 
-def make_params(shapes, key, embed_std: float = 0.02):
+def make_params(shapes, key, embed_std: float = 0.02, stack=stack_axes):
     """Fill the tree ``shapes`` (of ShapeDtypeStruct) from ``key`` in one
     jitted call on the default device. ``embed_std``: the scale of the
-    token embedding (truncated normal)."""
+    token embedding (truncated normal); ``stack(names, shape)``: how many
+    leading axes a leaf stacks over."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
     def fill(key):
-        leaves = [_leaf(jax.random.fold_in(key, i), _names(path),
-                        tuple(s.shape), s.dtype, embed_std)
-                  for i, (path, s) in enumerate(flat)]
+        leaves = []
+        for i, (path, s) in enumerate(flat):
+            names, shape = _names(path), tuple(s.shape)
+            leaves.append(_leaf(jax.random.fold_in(key, i), names, shape,
+                                s.dtype, embed_std, stack(names, shape)))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     return jax.block_until_ready(jax.jit(fill)(key))

@@ -2,11 +2,15 @@
 shapes. Nothing here counts what a kernel walks or pads; a kernel's
 roofline share is needed work over measured time.
 
-Model dicts are a tier's ``model`` block of a configuration file.
+Model dicts are a tier's ``model`` block of a configuration file. What
+differs by architecture is in its kind file (``kinds/<kind>.py``); the
+per-layer formulas here are shared by the kinds.
 """
 from __future__ import annotations
 
-from typing import Iterable, Tuple
+from typing import Iterable, Optional, Tuple
+
+from . import spec
 
 BF16 = 2
 
@@ -39,16 +43,10 @@ def attention_flops(m: dict, keys: float) -> float:
 
 
 def token_flops(kind: str, m: dict, keys: float) -> float:
-    """One token through the layers (not the LM head): matmuls, plus
-    attention over ``keys`` keys (decoder) or the state update (Mamba-2)."""
-    if kind == "decoder":
-        return 2.0 * decoder_layer_params(m) * m["n_layers"] \
-            + attention_flops(m, keys)
-    di = m.get("ssm_expand", 2) * m["d_model"]
-    P, N = m.get("ssm_headdim", 64), m["ssm_state"]
-    state = 6.0 * (di // P) * P * N      # decay, update, read-out of h
-    conv = 2.0 * m.get("ssm_conv_width", 4) * (di + 2 * N)
-    return (2.0 * mamba_layer_params(m) + state + conv) * m["n_layers"]
+    """One token through the layers of a ``kind`` tier (not the LM head),
+    attending over ``keys`` keys where it attends: the kind's own
+    ``token_flops``. A kind with no file raises."""
+    return spec.tier_kind(kind).token_flops(m, keys)
 
 
 def prefill_flops(kind: str, m: dict, start: int, n: int) -> float:
@@ -65,11 +63,14 @@ def router_flops(r: dict, L: int) -> float:
 
 
 # ------------------------------------------------------------ paged kernels
-def paged_decode_work(m: dict, contexts: Iterable[int]) -> Tuple[float, float]:
+def paged_decode_work(m: dict, contexts: Iterable[int],
+                      layers: Optional[int] = None) -> Tuple[float, float]:
     """(flops, bytes) of the paged decode attention for one query per
-    context (keys including the new token), over all layers: the live K
-    and V it must read, the query in and the output out."""
-    H, K, Dh, L = m["n_heads"], m["n_kv_heads"], head_dim(m), m["n_layers"]
+    context (keys including the new token), over ``layers`` attention
+    layers (all of the model's by default): the live K and V it must read,
+    the query in and the output out."""
+    H, K, Dh = m["n_heads"], m["n_kv_heads"], head_dim(m)
+    L = m["n_layers"] if layers is None else layers
     flops = byts = 0.0
     for c in contexts:
         flops += 4.0 * c * H * Dh * L
@@ -77,13 +78,14 @@ def paged_decode_work(m: dict, contexts: Iterable[int]) -> Tuple[float, float]:
     return flops, byts
 
 
-def paged_prefill_work(m: dict, chunks: Iterable[Tuple[int, int]]
-                       ) -> Tuple[float, float]:
+def paged_prefill_work(m: dict, chunks: Iterable[Tuple[int, int]],
+                       layers: Optional[int] = None) -> Tuple[float, float]:
     """(flops, bytes) of the paged prefill attention for chunks of
-    (start, n) — n queries, causal over start + n keys — over all layers:
-    K and V of the chunk's whole context read once, queries in, output
-    out."""
-    H, K, Dh, L = m["n_heads"], m["n_kv_heads"], head_dim(m), m["n_layers"]
+    (start, n) — n queries, causal over start + n keys — over ``layers``
+    attention layers (all of the model's by default): K and V of the
+    chunk's whole context read once, queries in, output out."""
+    H, K, Dh = m["n_heads"], m["n_kv_heads"], head_dim(m)
+    L = m["n_layers"] if layers is None else layers
     flops = byts = 0.0
     for s, n in chunks:
         flops += 4.0 * H * Dh * (n * s + n * (n + 1) / 2.0) * L
